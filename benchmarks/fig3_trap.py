@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import EAConfig, make_trap
 from repro.core import island as island_lib
 
@@ -81,6 +82,7 @@ def main(argv=None):
     ap.add_argument("--impl", choices=["jnp", "pallas"], default="jnp")
     ap.add_argument("--max-evals", type=int, default=5_000_000)
     args = ap.parse_args(argv)
+    use_compile_cache()
     rows = run(args.runs, impl=args.impl, max_evals=args.max_evals,
                verbose=True)
     print("\n".join(summarize(rows)))

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.problems import f15_ref, make_f15_consts
 from repro.kernels.rastrigin import ops as f15_ops
 
@@ -160,6 +161,7 @@ def main(argv=None):
                     help="CI trim: D=128, 256 evals, tiled generation "
                          "forced through a >=2x2x2 grid")
     args = ap.parse_args(argv)
+    use_compile_cache()
     rows = bench(args.n_evals, include_loop=not args.no_loop,
                  include_pallas=not args.no_pallas,
                  include_generation=not args.no_generation,

@@ -26,7 +26,7 @@ def env_block() -> Dict[str, Any]:
     pallas rows measure the interpreter emulation, not the hardware.
     """
     import jax
-    from repro.kernels import on_tpu
+    from repro.kernels import interpret_mode
 
     xla_flags = os.environ.get("XLA_FLAGS", "")
     m = re.search(r"--xla_force_host_platform_device_count=(\d+)",
@@ -35,7 +35,7 @@ def env_block() -> Dict[str, Any]:
         "xla_flags": xla_flags,
         "jax_enable_x64": bool(jax.config.jax_enable_x64),
         "host_platform_device_count": int(m.group(1)) if m else None,
-        "pallas_interpret": not on_tpu(),
+        "pallas_interpret": interpret_mode(),
         "jax_default_prng_impl": str(
             getattr(jax.config, "jax_default_prng_impl", "threefry2x32")),
     }

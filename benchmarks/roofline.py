@@ -25,15 +25,12 @@ bytes per evaluation; the arithmetic per gene is trivial), so
     ceiling_evals_per_sec = HBM_bw / (2 * L * itemsize)
 
 and ``roofline_fraction = measured / ceiling`` says how far each engine
-sits below the memory wall. Off-TPU the pallas rows measure interpret-mode
-emulation (fractions are tiny and meaningless for hardware placement —
-the stamped env block says which reading applies); the rows are emitted
-into ``BENCH_speed.json`` either way so the trajectory exists from the
-first commit.
+sits below the memory wall. On the CPU there is no device to place, so the
+rows carry their times and ``roofline_fraction: "not measured"``.
 
 Hardware constants are a per-``device_kind`` table (:data:`HW_TABLE`) with
-a CLI override (``--peak-flops/--hbm-bw/--ici-bw``); unknown device kinds
-fall back to the TPU v5e row, loudly, in the ``hw`` field of every record.
+a CLI override (``--peak-flops/--hbm-bw/--ici-bw``). A device kind that is
+not in the table is an error, not a default.
 """
 from __future__ import annotations
 
@@ -46,15 +43,13 @@ from typing import Any, Dict, List, Optional
 
 # Per-device_kind hardware constants (bf16 peak, HBM bandwidth, per-link
 # ICI bandwidth). Keys are matched case-insensitively as substrings of
-# jax's device_kind string ("TPU v5 lite" etc.); first match wins.
+# jax's device_kind string ("TPU v5 lite" is a v5e); first match wins.
 HW_TABLE: Dict[str, Dict[str, float]] = {
     "v5 lite": {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
     "v5e":     {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
     "v5p":     {"peak_flops": 459e12, "hbm_bw": 2765e9, "ici_bw": 90e9},
     "v4":      {"peak_flops": 275e12, "hbm_bw": 1228e9, "ici_bw": 50e9},
     "v3":      {"peak_flops": 123e12, "hbm_bw": 900e9, "ici_bw": 70e9},
-    # generic host fallback so CPU smoke runs produce finite ceilings
-    "cpu":     {"peak_flops": 0.5e12, "hbm_bw": 40e9, "ici_bw": 10e9},
 }
 _DEFAULT_KIND = "v5e"
 
@@ -71,20 +66,18 @@ def hw_constants(device_kind: Optional[str] = None,
                  override: Optional[Dict[str, float]] = None
                  ) -> Dict[str, Any]:
     """Resolve hardware constants for ``device_kind`` (defaults to the
-    current jax device), applying any non-None ``override`` entries."""
+    current jax device), applying any non-None ``override`` entries.
+    Raises ``KeyError`` for a device kind that is not in :data:`HW_TABLE`."""
     if device_kind is None:
         import jax
-        device_kind = getattr(jax.devices()[0], "device_kind",
-                              jax.default_backend())
-    matched = None
-    for key, row in HW_TABLE.items():
-        if key.lower() in str(device_kind).lower():
-            matched = key
-            break
-    row = dict(HW_TABLE[matched or _DEFAULT_KIND])
-    out = {"device_kind": str(device_kind),
-           "table_entry": matched or f"{_DEFAULT_KIND} (fallback)",
-           **row}
+        device_kind = jax.devices()[0].device_kind
+    matched = next((key for key in HW_TABLE
+                    if key.lower() in str(device_kind).lower()), None)
+    if matched is None:
+        raise KeyError(f"no hardware constants for device kind "
+                       f"{device_kind!r}; known: {sorted(HW_TABLE)}")
+    out = {"device_kind": str(device_kind), "table_entry": matched,
+           **HW_TABLE[matched]}
     for k, v in (override or {}).items():
         if v is not None:
             out[k] = float(v)
@@ -234,11 +227,16 @@ def generation_roofline(impls=("jnp", "pallas", "pallas_tiled"), *,
                         hw: Optional[Dict[str, Any]] = None
                         ) -> Dict[str, Any]:
     """Measure generation evals/sec per impl and place each against the
-    memory-bandwidth roofline. Returns the BENCH_speed.json section."""
-    hw = hw or hw_constants()
+    memory-bandwidth roofline. Returns the BENCH_speed.json section. On
+    the CPU backend nothing is placed: the roofline share is "not
+    measured"."""
+    import jax
+
+    if hw is None and jax.default_backend() != "cpu":
+        hw = hw_constants()
     itemsize = 1 if kind == "binary" else 4
     bytes_per_eval = 2 * L * itemsize      # mandatory: read pop + write pop
-    ceiling = hw["hbm_bw"] / bytes_per_eval
+    ceiling = hw["hbm_bw"] / bytes_per_eval if hw else None
     rows = []
     for impl in impls:
         sec = _bench_generation(impl, n, L, kind, repeats, tile_pop,
@@ -249,14 +247,15 @@ def generation_roofline(impls=("jnp", "pallas", "pallas_tiled"), *,
             "genome_kind": kind,
             "evals_per_sec": eps,
             "seconds_per_generation": sec,
-            "roofline_fraction": eps / ceiling,
+            "roofline_fraction": (eps / ceiling if ceiling
+                                  else "not measured"),
         })
     return {
         "metric": "single generation-step throughput vs HBM roofline "
-                  "(ceiling = hbm_bw / (2 * L * itemsize); off-TPU the "
-                  "pallas rows time interpret-mode emulation — see "
-                  "host.env.pallas_interpret)",
-        "hw": hw,
+                  "(ceiling = hbm_bw / (2 * L * itemsize); on the CPU the "
+                  "pallas rows time interpret-mode emulation and the "
+                  "roofline share is not measured)",
+        "hw": hw or "not measured",
         "bytes_per_eval_min": bytes_per_eval,
         "ceiling_evals_per_sec": ceiling,
         "rows": rows,
@@ -271,9 +270,9 @@ def _hw_override(args) -> Dict[str, float]:
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", default="16x16")
-    ap.add_argument("--device-kind", default=None,
-                    help="override HW_TABLE lookup (default: current jax "
-                         "device)")
+    ap.add_argument("--device-kind", default=_DEFAULT_KIND,
+                    help="HW_TABLE row for the dry-run table (the dry-run "
+                         "compiles for a v5e pod, wherever it runs)")
     ap.add_argument("--peak-flops", type=float, default=None)
     ap.add_argument("--hbm-bw", type=float, default=None)
     ap.add_argument("--ici-bw", type=float, default=None)
@@ -289,12 +288,14 @@ def main(argv=None):
     print(f"# hw: {hw}")
     print("\n".join(table(args.mesh, hw=hw)))
     if args.generation:
+        from repro.compile_cache import use_compile_cache
+        use_compile_cache()
         section = generation_roofline(n=args.pop, L=args.genome_length,
-                                      kind=args.kind, hw=hw)
+                                      kind=args.kind)
         print("impl,pop,L,evals_per_sec,roofline_fraction")
         for r in section["rows"]:
             print(f"{r['impl']},{r['pop']},{r['genome_length']},"
-                  f"{r['evals_per_sec']:.0f},{r['roofline_fraction']:.2e}")
+                  f"{r['evals_per_sec']:.0f},{r['roofline_fraction']}")
 
 
 if __name__ == "__main__":

@@ -26,6 +26,8 @@ def main(argv=None) -> None:
                          "genome length x generation-engine impl)")
     args = ap.parse_args(argv)
     from benchmarks import hostmeta
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     t0 = time.perf_counter()
 
     if "fig3" not in args.skip:

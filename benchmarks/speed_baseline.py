@@ -40,6 +40,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import EAConfig, MigrationConfig, make_problem, run_fused
 
 # One scenario = one paper-table row family: a problem at a genome length,
@@ -203,6 +204,7 @@ def main(argv=None):
     ap.add_argument("--epochs", type=int, default=None)
     ap.add_argument("--json", default="BENCH_speed.json")
     args = ap.parse_args(argv)
+    use_compile_cache()
     rows = run(full=args.full, impls=args.impls, runs=args.runs,
                islands=args.islands, epochs=args.epochs, verbose=True)
     print("\n".join(summarize(rows)))

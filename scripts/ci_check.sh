@@ -4,10 +4,9 @@
 #   scripts/ci_check.sh            # from anywhere inside the repo
 #
 # KNOWN_FAILING lists modules with pre-existing breakage excluded from the
-# gate. Currently EMPTY: the jax-0.4.37 API-drift quarantine (AbstractMesh /
-# get_abstract_mesh / set_mesh / shard_map drift) was burned down by the
-# repro.compat shims — the gate is strict on the whole suite. Add entries
-# only with a tracking note in ROADMAP.md.
+# gate. Currently EMPTY: the gate is strict on the whole suite, on the one
+# pinned JAX (requirements-ci.txt: jax/jaxlib 0.9.0). Add entries only with
+# a tracking note in ROADMAP.md.
 #
 # The benchmark smoke runs the pool + migration + speed sections only
 # (fig3 replays paper-scale evolution and roofline's dry-run section needs

@@ -1,128 +1,26 @@
-"""Version compatibility shims.
+"""Mesh helpers shared by the launchers, the models and the tests.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` (<= 0.4.x, kwarg
-``check_rep``) to ``jax.shard_map`` (>= 0.5, kwarg ``check_vma``). Import it
-from here so every call site works on both:
-
-    from repro.compat import shard_map
-    fn = shard_map(body, mesh=mesh, in_specs=..., out_specs=..., check=False)
-
-The same goes for the mesh-context API: ``jax.set_mesh`` /
-``jax.sharding.get_abstract_mesh`` / ``jax.sharding.AxisType`` and the
-two-argument ``AbstractMesh(axis_sizes, axis_names)`` constructor only exist
-on newer jax. :func:`set_mesh`, :func:`get_abstract_mesh` and
-:func:`abstract_mesh` paper over the drift.
+:func:`make_mesh` pins every axis to ``AxisType.Auto`` (the sharding
+rules in ``launch/shardings.py`` assume automatic propagation), and
+:func:`get_abstract_mesh` maps "no mesh set" to ``None``.
 """
 from __future__ import annotations
 
-import contextlib
-import inspect
-import threading
 
-try:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:  # jax >= 0.5: promoted to the top-level namespace
-    from jax import shard_map as _shard_map
-
-_PARAMS = inspect.signature(_shard_map).parameters
-_CHECK_KW = ("check_rep" if "check_rep" in _PARAMS
-             else "check_vma" if "check_vma" in _PARAMS else None)
-
-
-def shard_map(f, mesh, in_specs, out_specs, check: bool = True):
-    kwargs = {_CHECK_KW: check} if _CHECK_KW is not None else {}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
-
-
-def axis_size(axis_name):
-    """Static size of a mapped mesh axis, from inside shard_map/pmap.
-    ``jax.lax.axis_size`` is newer-jax only; ``psum(1, axis)`` is the
-    classic constant-folded equivalent."""
+def make_mesh(shape, axis_names, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``; ``devices``
+    restricts the mesh to those devices."""
     import jax
 
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def make_mesh(shape, axis_names):
-    """``jax.make_mesh`` across versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer jax; fall back to the
-    plain call when they don't."""
-    import jax
-
-    try:
-        axis_types = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    except AttributeError:
-        return jax.make_mesh(shape, axis_names)
-    return jax.make_mesh(shape, axis_names, axis_types=axis_types)
-
-
-def abstract_mesh(axis_sizes, axis_names):
-    """``jax.sharding.AbstractMesh`` across versions. Newer jax takes
-    ``(axis_sizes, axis_names)``; 0.4.x takes a single
-    ``((name, size), ...)`` shape tuple."""
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:  # 0.4.x signature: shape_tuple of (name, size) pairs
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-
-
-# Fallback mesh-context stack for jax without jax.set_mesh (one per thread:
-# trace-time lookups happen on the tracing thread).
-_MESH_CTX = threading.local()
-
-
-def set_mesh(mesh):
-    """Context manager mirroring ``jax.set_mesh(mesh)``. On older jax the
-    mesh is pushed onto a thread-local stack (read back by
-    :func:`get_abstract_mesh`) and entered as the legacy ``Mesh`` context so
-    pjit-era mesh resolution still sees it."""
-    import jax
-
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-
-    @contextlib.contextmanager
-    def cm():
-        stack = getattr(_MESH_CTX, "stack", None)
-        if stack is None:
-            stack = _MESH_CTX.stack = []
-        stack.append(mesh)
-        try:
-            if hasattr(mesh, "__enter__"):  # concrete Mesh context manager
-                with mesh:
-                    yield mesh
-            else:                           # AbstractMesh: stack only
-                yield mesh
-        finally:
-            stack.pop()
-
-    return cm()
+    axis_types = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(shape, axis_names, axis_types=axis_types,
+                         devices=devices)
 
 
 def get_abstract_mesh():
-    """The mesh set by :func:`set_mesh` (or ``jax.set_mesh``), else ``None``.
-
-    Unlike newer jax (which returns an *empty* ``AbstractMesh``), the
-    no-mesh case is ``None`` — callers must treat None and an empty mesh
-    alike (both: no named axes to shard over)."""
+    """The mesh set by ``jax.set_mesh``, else ``None`` (JAX itself returns
+    an *empty* ``AbstractMesh`` when none is set)."""
     import jax
 
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        m = jax.sharding.get_abstract_mesh()
-        return m if m is None or m.axis_names else None
-    stack = getattr(_MESH_CTX, "stack", None)
-    if stack:
-        return stack[-1]
-    try:  # legacy `with mesh:` context (pjit-era thread resources)
-        from jax._src import mesh as _mesh_src
-        env_mesh = _mesh_src.thread_resources.env.physical_mesh
-        if env_mesh is not None and not env_mesh.empty:
-            return env_mesh
-    except Exception:  # noqa: BLE001 — private API may move; treat as unset
-        pass
-    return None
+    m = jax.sharding.get_abstract_mesh()
+    return m if m is not None and m.axis_names else None

@@ -78,7 +78,7 @@ import numpy as np
 
 from .types import AcceptanceConfig, Array, PoolState
 
-NEG_INF = jnp.float32(-jnp.inf)
+NEG_INF = float("-inf")  # not a jnp scalar: that would start a backend
 
 
 # ---------------------------------------------------------------------------

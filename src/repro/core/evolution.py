@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import axis_size
 from repro.obs import counters as obs_lib
 from repro.obs import trace as obs_trace
 
@@ -124,7 +123,7 @@ def collect_stats(islands: IslandState, epoch: Array | int,
     n_done = islands.done.sum()
     solved = islands.experiments.sum()
     if axis is not None:
-        n_shards = axis_size(axis)
+        n_shards = jax.lax.axis_size(axis)
         best = jax.lax.pmax(best, axis)
         mean = jax.lax.psum(mean, axis) / n_shards  # equal n_local per shard
         evals = jax.lax.psum(evals, axis)

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 
 from .types import Array, EAConfig, GenomeSpec
 
-NEG_INF = jnp.float32(-jnp.inf)
+NEG_INF = float("-inf")  # not a jnp scalar: that would start a backend
 
 
 def mask_fitness(fitness: Array, pop_size: Array) -> Array:

@@ -71,7 +71,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import axis_size
 from repro.obs import trace as obs_trace
 
 from . import acceptance as acceptance_lib
@@ -250,7 +249,7 @@ def ring_topology(pool: PoolState, bests_genome: Array, bests_fitness: Array,
     if vec is not None:  # async fire mask: silent sources contribute -inf
         bests_fitness = jnp.where(vec, bests_fitness, NEG_INF)
     if axis is not None:
-        n = axis_size(axis)
+        n = jax.lax.axis_size(axis)
         perm = [(i, (i + 1) % n) for i in range(n)]
         imm_g = jax.lax.ppermute(bests_genome, axis, perm)
         imm_f = jax.lax.ppermute(bests_fitness, axis, perm)
@@ -282,7 +281,7 @@ def torus_topology(pool: PoolState, bests_genome: Array, bests_fitness: Array,
         bests_fitness = jnp.where(vec, bests_fitness, NEG_INF)
     east = jnp.asarray(epoch) % 2 == 0
     if axis is not None:
-        n = axis_size(axis)
+        n = jax.lax.axis_size(axis)
         R, C = _grid(n)
         perm_e = [(r * C + c, r * C + (c + 1) % C)
                   for r in range(R) for c in range(C)]
@@ -339,7 +338,7 @@ def random_graph_topology(pool: PoolState, bests_genome: Array,
     if vec is not None:
         bests_fitness = jnp.where(vec, bests_fitness, NEG_INF)
     if axis is not None:
-        n = axis_size(axis)
+        n = jax.lax.axis_size(axis)
         perm = jax.random.permutation(rng, n)
         # (n_shards, n_local, ...) stacks; every shard indexes its source.
         all_g = jax.lax.all_gather(bests_genome, axis)

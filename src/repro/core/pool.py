@@ -26,7 +26,7 @@ from . import acceptance as acceptance_lib
 from .types import (AcceptanceConfig, Array, GenomeSpec, MigrationConfig,
                     PoolState)
 
-NEG_INF = jnp.float32(-jnp.inf)
+NEG_INF = float("-inf")  # not a jnp scalar: that would start a backend
 
 _ALWAYS = AcceptanceConfig()
 

@@ -27,6 +27,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ordered_sum
+
+# f32 matmuls at full f32 precision: the TPU's default rounds operands to bf16
+HIGHEST = jax.lax.Precision.HIGHEST
+
 from .types import Array, GenomeSpec
 
 
@@ -77,7 +82,7 @@ def trap_fitness_ref(consts: Dict[str, float], pop: Array) -> Array:
     blocks = pop.reshape(n, -1, l).astype(jnp.float32)
     u = blocks.sum(-1)
     f = jnp.where(u <= z, a * (z - u) / z, b * (u - z) / (l - z))
-    return f.sum(-1)
+    return ordered_sum(f)  # the fused kernels' order, on every backend
 
 
 def make_trap(n_traps: int = 40, l: int = 4, a: float = 1.0, b: float = 2.0,
@@ -197,7 +202,7 @@ def f15_ref(consts: Dict[str, Array], pop: Array) -> Array:
     n_groups, m, _ = M.shape
     z = (pop - o)[:, perm]
     zg = z.reshape(pop.shape[0], n_groups, m)
-    rot = jnp.einsum("ngm,gmk->ngk", zg, M)
+    rot = jnp.einsum("ngm,gmk->ngk", zg, M, precision=HIGHEST)
     return rastrigin(rot).sum(-1)
 
 

@@ -43,7 +43,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.obs import counters as obs_lib
 from repro.obs.counters import ObsCounters
 
@@ -87,12 +86,12 @@ def make_sharded_epoch(mesh: Mesh, axis: str, problem: Problem,
         return evolution_lib.epoch_step(islands, pool, rng, problem, cfg,
                                         mig, w2, available, epoch, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(_island_spec(axis), _pool_spec(), P(), None, P()),
         out_specs=(_island_spec(axis), _pool_spec()),
-        check=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -240,7 +239,7 @@ def run_fused_sharded(mesh: Mesh, problem: Problem,
                 *[P()] * len(ExperimentStats._fields))
                 if return_stats else ())
             obs_spec = _obs_spec(axis, return_obs)
-            fn = shard_map(
+            fn = jax.shard_map(
                 partial(evolution_lib.fused_scan, problem=problem, cfg=cfg,
                         mig=mig, w2=w2, max_epochs=seg_len, axis=axis,
                         with_stats=return_stats),
@@ -249,7 +248,7 @@ def run_fused_sharded(mesh: Mesh, problem: Problem,
                           obs_spec),
                 out_specs=(_island_spec(axis), _pool_spec(), P(), P(), P(),
                            obs_spec, stats_spec),
-                check=False,
+                check_vma=False,
             )
             return jax.jit(fn, donate_argnums=(0, 1))
 
@@ -343,7 +342,7 @@ def run_fused_sharded_async(mesh: Mesh, problem: Problem,
                 *[P()] * len(ExperimentStats._fields))
                 if return_stats else ())
             obs_spec = _obs_spec(axis, return_obs)
-            fn = shard_map(
+            fn = jax.shard_map(
                 partial(async_lib.fused_scan_async, problem=problem,
                         cfg=cfg, mig=mig, acfg=acfg, w2=w2,
                         max_ticks=seg_len, axis=axis,
@@ -354,7 +353,7 @@ def run_fused_sharded_async(mesh: Mesh, problem: Problem,
                 out_specs=(_island_spec(axis), _pool_spec(),
                            _astate_spec(axis), P(), P(), P(), obs_spec,
                            stats_spec),
-                check=False,
+                check_vma=False,
             )
             return jax.jit(fn, donate_argnums=(0, 1, 2))
 

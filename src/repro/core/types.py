@@ -60,7 +60,7 @@ class EAConfig:
     engine axis (repro.kernels.ga registry): 'jnp' (the classic four-op
     jax.random path, the default and the legacy-exact anchor), 'pallas'
     (the fused selection->crossover->mutation[->fitness] VMEM megakernel
-    with on-chip counter RNG; interpret-mode off-TPU), 'pallas_ref' (the
+    with on-chip counter RNG; interpret mode on the CPU), 'pallas_ref' (the
     megakernel's pure-jnp oracle — same counter RNG, bit-exact vs 'pallas'
     in interpret mode for binary genomes), or any custom registration.
     Note 'pallas'/'pallas_ref' draw their randomness from a different

@@ -4,7 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .. import on_tpu
+from .. import interpret_mode
 from . import flash_attention as _k
 from . import ref as _ref
 
@@ -39,6 +39,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         # non-causal path cannot mask pads inside the kernel -> fall back
         return _ref.attention(q, k, v, causal=causal, scale=scale)
     out = _k.flash_attention_kernel(qt, kt, vt, scale=scale, causal=causal,
-                                    bq=bq, bk=bk, interpret=not on_tpu())
+                                    bq=bq, bk=bk, interpret=interpret_mode())
     out = jnp.moveaxis(out, 1, 2)
     return out[:, :Sq]

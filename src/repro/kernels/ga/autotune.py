@@ -10,27 +10,30 @@ VMEM. The right point is device-dependent, so:
   runs (synthetic population of the requested shape, ``block_until_ready``
   timing of the steady state after one warm-up) and picks the highest
   evals/sec.
-* **Off TPU** (interpret mode — CI, laptops) timing is meaningless, so a
+  A candidate the compiler refuses is reported; if none compiles, the
+  sweep raises.
+* **On the CPU** (interpret mode — tests, CI) timing is meaningless, so a
   VMEM-model heuristic picks the largest candidate under the budget.
 
-Results are cached as JSON keyed by ``jax.devices()[0].device_kind`` at
-``benchmarks/results/autotune_ga.json`` (override with the
-``REPRO_GA_AUTOTUNE_CACHE`` env var) so a sweep runs once per device
-kind; ``benchmarks/hostmeta.py`` folds the cache into the BENCH host
-block, which is how tuned tile sizes travel with published numbers.
+Results are cached as JSON keyed by device kind, genome kind and shape
+(:func:`cache_key`) at ``benchmarks/results/autotune_ga.json`` (override
+with the ``REPRO_GA_AUTOTUNE_CACHE`` env var) so a sweep runs once per
+key; ``benchmarks/hostmeta.py`` folds the cache into the BENCH host block,
+which is how tuned tile sizes travel with published numbers.
 """
 from __future__ import annotations
 
 import json
 import os
 import time
+import warnings
 from pathlib import Path
 from typing import Dict, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
+import numpy as np
 
-from .. import on_tpu
+from .. import interpret_mode
 
 # (tile_pop, tile_len) sweep grid — all MXU/VPU-aligned.
 CANDIDATES: Tuple[Tuple[int, int], ...] = (
@@ -97,27 +100,29 @@ def _time_candidate(n: int, L: int, kind: str, tp: int, tl: int,
               else GenomeSpec("float", L, -5.0, 5.0))
     cfg = EAConfig(max_pop=n, min_pop=min(8, n))
     spec = _ops.make_spec(cfg, genome)
-    rng = jax.random.key(0)
-    # distinct init key: drawing the pop with the same key that seeds the
-    # kernel's counter RNG correlates init genomes with mutation noise
-    k_init = jax.random.fold_in(rng, 1)
-    pop = (jax.random.bernoulli(k_init, 0.5, (n, L)).astype(jnp.int8)
-           if kind == "binary"
-           else jax.random.uniform(k_init, (n, L), jnp.float32, -5.0, 5.0))
-    fit = pop.astype(jnp.float32).sum(-1)
-    seed = _ops._seed_words(rng)
-    size = _ops._size_vec(n)
-
-    step = jax.jit(lambda: _tiling.generation_tiled(
+    # host-made inputs and an AOT-compiled step: best_tiles runs while the
+    # caller's kernel is being traced, and nothing here may join that trace
+    gen = np.random.default_rng(0)
+    pop = (gen.integers(0, 2, (n, L), dtype=np.int8) if kind == "binary"
+           else gen.uniform(-5.0, 5.0, (n, L)).astype(np.float32))
+    args = (np.array([0x9E3779B9, 1], np.uint32), np.array([n], np.int32),
+            pop, pop.astype(np.float32).sum(-1))
+    step = jax.jit(lambda seed, size, pop, fit: _tiling.generation_tiled(
         seed, size, pop, fit, spec, tile_pop=tp, tile_len=tl,
-        interpret=False))
-    step().block_until_ready()  # compile + warm up
+        interpret=False)).lower(*args).compile()
+    step(*args).block_until_ready()  # warm up
     times = []
     for _ in range(runs):
         t0 = time.perf_counter()
-        step().block_until_ready()
+        step(*args).block_until_ready()
         times.append(time.perf_counter() - t0)
     return sorted(times)[len(times) // 2]
+
+
+def cache_key(n: int, L: int, kind: str) -> str:
+    """Cache entries are per device kind, genome kind and shape: the best
+    tiling of one shape says nothing about another."""
+    return f"{device_kind()}|{kind}|{int(n)}x{int(L)}"
 
 
 def best_tiles(n: int, L: int, kind: str = "float", *,
@@ -125,36 +130,38 @@ def best_tiles(n: int, L: int, kind: str = "float", *,
                force: bool = False) -> Tuple[int, int]:
     """Tuned (tile_pop, tile_len) for a (n, L) population of ``kind``.
 
-    Reads the per-device_kind cache first; on a cache miss sweeps (TPU)
-    or applies the VMEM heuristic (interpret mode) and writes the cache.
+    Reads the cache first; on a miss sweeps (TPU) or applies the VMEM
+    heuristic (interpret mode) and writes the cache. On the TPU each
+    candidate the compiler refuses is reported, and a sweep in which no
+    candidate compiles raises rather than falling back to the heuristic.
     """
     cache = load_cache(cache_path)
-    key = device_kind()
+    key = cache_key(n, L, kind)
     entry = cache.get(key)
     if entry is not None and not force:
         return int(entry["tile_pop"]), int(entry["tile_len"])
 
-    if on_tpu():
-        timings = {}
+    entry = {"shape": [int(n), int(L)], "kind": kind}
+    if interpret_mode():
+        tp, tl = _heuristic(n, L)
+        entry.update(tile_pop=tp, tile_len=tl, timed=False)
+    else:
+        timings, refused = {}, {}
         for tp, tl in CANDIDATES:
             try:
                 timings[(tp, tl)] = _time_candidate(n, L, kind, tp, tl)
-            except Exception:  # candidate may exceed VMEM — skip it
-                continue
-        if timings:
-            (tp, tl) = min(timings, key=timings.get)
-            entry = {"tile_pop": tp, "tile_len": tl, "timed": True,
-                     "shape": [int(n), int(L)], "kind": kind,
-                     "sweep_s": {f"{a}x{b}": t
-                                 for (a, b), t in sorted(timings.items())}}
-        else:
-            tp, tl = _heuristic(n, L)
-            entry = {"tile_pop": tp, "tile_len": tl, "timed": False,
-                     "shape": [int(n), int(L)], "kind": kind}
-    else:
-        tp, tl = _heuristic(n, L)
-        entry = {"tile_pop": tp, "tile_len": tl, "timed": False,
-                 "shape": [int(n), int(L)], "kind": kind}
+            except (ValueError, NotImplementedError,
+                    jax.errors.JaxRuntimeError) as e:
+                refused[f"{tp}x{tl}"] = str(e).splitlines()[0][:300]
+                warnings.warn(f"autotune {key}: tile {tp}x{tl} refused: "
+                              f"{refused[f'{tp}x{tl}']}", stacklevel=2)
+        if not timings:
+            raise RuntimeError(f"autotune {key}: no tile candidate "
+                               f"compiled: {refused}")
+        tp, tl = min(timings, key=timings.get)
+        entry.update(tile_pop=tp, tile_len=tl, timed=True, refused=refused,
+                     sweep_s={f"{a}x{b}": t
+                              for (a, b), t in sorted(timings.items())})
 
     cache[key] = entry
     try:

@@ -42,6 +42,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import ordered_sum
 from . import prng
 
 # python float, not a jnp scalar: a module-level jnp constant would be a
@@ -57,6 +58,12 @@ SALT_CROSSOVER = 0xC3
 SALT_CROSSOVER_GATE = 0xD4
 SALT_MUTATE = 0xE5
 SALT_MUTATE_NOISE = 0xF6
+
+# Full f32 precision for matmuls of two arbitrary f32 operands (F15
+# rotations, roulette prefix sums): on the TPU the default precision of
+# XLA and Mosaic rounds f32 operands to bf16. One-hot gathers use
+# onehot_matmul instead; 0/1 block sums are exact at any precision.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 # Population-axis block size for the O(n^2) selection reductions
 # (tournament candidate-fitness gather, roulette prefix sum / inverse
@@ -130,20 +137,68 @@ def _lanes(n: int) -> jax.Array:
     return jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0).reshape(n)
 
 
-def _gather_rows(popf: jax.Array, idx: jax.Array) -> jax.Array:
-    """Row gather as a one-hot matmul: (m,) indices from (n, L) -> (m, L).
+def _bf16_parts(x: jax.Array):
+    """Split f32 ``x`` into three f32 arrays whose values are exact in
+    bf16 and sum to ``x`` exactly: each keeps the top 16 bits of what the
+    previous parts left over. Bit masks, not casts, so no compiler may
+    fold the rounding away."""
+    def top16(v):
+        bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                            jnp.float32)
+    hi = top16(x)
+    mid = top16(x - hi)
+    return hi, mid, x - hi - mid
 
-    MXU-native on TPU; exact for any float payload either way because each
-    output row is 1*row + 0*rest.
-    """
+
+def onehot_matmul(a: jax.Array, b: jax.Array, split=None) -> jax.Array:
+    """``a @ b`` in f32 where one operand is a 0/1 one-hot, so each output
+    element picks at most one value of the other operand.
+
+    0/1 genes are exact in one default-precision pass. Float values are
+    not: the TPU's default precision rounds f32 operands to bf16, and
+    HIGHEST also splits the one-hot, which overflows VMEM at island sizes.
+    ``split`` names the float operand (``"a"`` or ``"b"``): it goes through
+    as three bf16-exact parts, which add back to each picked value bit for
+    bit."""
+    dot = lambda x, y: jnp.dot(x, y,  # noqa: E731
+                               preferred_element_type=jnp.float32)
+    if split is None:
+        return dot(a, b)
+    if split == "a":
+        hi, mid, lo = _bf16_parts(a)
+        return dot(hi, b) + dot(mid, b) + dot(lo, b)
+    hi, mid, lo = _bf16_parts(b)
+    return dot(a, hi) + dot(a, mid) + dot(a, lo)
+
+
+def _gather_rows(popf: jax.Array, idx: jax.Array, split) -> jax.Array:
+    """Row gather as a one-hot matmul: (m,) indices from (n, L) -> (m, L).
+    MXU-native on TPU; bit-exact (see :func:`onehot_matmul`)."""
     n = popf.shape[0]
     onehot = (idx[:, None] == _lanes(n)[None, :]).astype(jnp.float32)
-    return jnp.dot(onehot, popf, preferred_element_type=jnp.float32)
+    return onehot_matmul(onehot, popf, split)
+
+
+def gather_split(spec: "GenerationSpec"):
+    """The ``split`` of :func:`onehot_matmul` for a parent gather: float
+    genes need it, 0/1 genes do not."""
+    return "b" if spec.kind == "float" else None
+
+
+def _first_argmax(x: jax.Array) -> jax.Array:
+    """Argmax over the last axis that picks the first maximum on ties, as
+    ``jnp.argmax`` does in XLA. Built from max/min/compare: Mosaic's own
+    arg-max reduction does not promise which tied index it returns, and
+    trap fitness ties often."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    top = jnp.max(x, axis=-1, keepdims=True)
+    return jnp.min(jnp.where(x == top, idx, x.shape[-1]), axis=-1)
 
 
 def _argmax_lane(v: jax.Array) -> jax.Array:
     """Scalar argmax of a (n,) vector via a (1, n) reduction (TPU-safe)."""
-    return jnp.argmax(v.reshape(1, -1), axis=1)[0]
+    return _first_argmax(v.reshape(1, -1))[0]
 
 
 def _tournament(k0, k1, masked: jax.Array, maxval: jax.Array,
@@ -156,15 +211,21 @@ def _tournament(k0, k1, masked: jax.Array, maxval: jax.Array,
     n = masked.shape[0]
     block = min(n, block or SELECTION_BLOCK)
     cand = prng.randint(k0, k1, (n_children, k), maxval, salt)
-    cand_f = jnp.full((n_children, k), NEG_INF, jnp.float32)
-    for b0 in range(0, n, block):
-        bs = min(block, n - b0)
-        lanes_b = b0 + _lanes(bs)
-        hit = cand[:, :, None] == lanes_b[None, None, :]
-        part = jnp.max(jnp.where(hit, masked[b0:b0 + bs][None, None, :],
-                                 NEG_INF), axis=-1)
-        cand_f = jnp.maximum(cand_f, part)
-    win = jnp.argmax(cand_f, axis=1)
+    # one 2-D (n_children, block) compare per candidate column: a 3-D
+    # (n_children, k, block) compare pads k to a full sublane tile on TPU
+    cols = []
+    for j in range(k):
+        cf = jnp.full((n_children,), NEG_INF, jnp.float32)
+        for b0 in range(0, n, block):
+            bs = min(block, n - b0)
+            lanes_b = b0 + _lanes(bs)
+            hit = cand[:, j:j + 1] == lanes_b[None, :]
+            part = jnp.max(jnp.where(hit, masked[b0:b0 + bs][None, :],
+                                     NEG_INF), axis=-1)
+            cf = jnp.maximum(cf, part)
+        cols.append(cf[:, None])
+    cand_f = jnp.concatenate(cols, axis=1)
+    win = _first_argmax(cand_f)
     ks = jax.lax.broadcasted_iota(jnp.int32, (n_children, k), 1)
     return jnp.sum(jnp.where(ks == win[:, None], cand, 0), axis=1)
 
@@ -194,7 +255,8 @@ def _roulette(k0, k1, masked: jax.Array, maxval: jax.Array,
         ci = jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 1)
         tril = (ci <= ri).astype(jnp.float32)
         cb = jnp.dot(tril, w[b0:b0 + bs][:, None],
-                     preferred_element_type=jnp.float32)[:, 0] + carry
+                     preferred_element_type=jnp.float32,
+                     precision=HIGHEST)[:, 0] + carry
         cums.append(cb)
         carry = cb[bs - 1]
     cum = cums[0] if len(cums) == 1 else jnp.concatenate(cums)
@@ -271,10 +333,11 @@ def child_tile_math(k0: jax.Array, k1: jax.Array, pa: jax.Array,
     """Crossover + mutation of one (rows, cols) output tile.
 
     ``pa``/``pb`` are the gathered parent tiles (f32); ``cut1``/``cut2``/
-    ``gate`` the matching plan rows. ``(row0, col0)`` is the tile origin in
-    the global (max_pop, length) output — all per-element randomness is
-    drawn with global counter offsets so any tiling produces bit-identical
-    genes. Elite rows (global row < spec.elite) pass parent A through
+    ``gate`` the matching plan rows as (rows, 1) columns, the layout the
+    tiled kernel's row blocks arrive in. ``(row0, col0)`` is the tile
+    origin in the global (max_pop, length) output — all per-element
+    randomness is drawn with global counter offsets so any tiling produces
+    bit-identical genes. Elite rows (global row < spec.elite) pass parent A through
     untouched. Returns the f32 tile (cast to the population dtype by the
     caller)."""
     R, C = pa.shape
@@ -289,7 +352,7 @@ def child_tile_math(k0: jax.Array, k1: jax.Array, pa: jax.Array,
     # --- crossover
     if spec.crossover == "two_point":
         pos = jax.lax.broadcasted_iota(jnp.int32, (R, C), 1) + col0
-        inside = (pos >= cut1[:, None]) & (pos < cut2[:, None])
+        inside = (pos >= cut1) & (pos < cut2)
         kids = jnp.where(inside, pb, pa)
     elif spec.crossover == "uniform":
         take = prng.bernoulli(k0, k1, (R, C), 0.5, SALT_CROSSOVER, off,
@@ -300,7 +363,7 @@ def child_tile_math(k0: jax.Array, k1: jax.Array, pa: jax.Array,
         u = (prng.uniform(k0, k1, (R, C), SALT_CROSSOVER, off, length)
              * (1.0 + 2.0 * a) - a)
         kids = pa + u * (pb - pa)
-    kids = jnp.where(gate[:, None] != 0, kids, pa)
+    kids = jnp.where(gate != 0, kids, pa)
 
     # --- mutation (children only; elite rows pass through)
     hits = prng.bernoulli(k0, k1, (R, C), spec.mutation_rate, SALT_MUTATE,
@@ -323,6 +386,18 @@ def rastrigin_terms(rot: jax.Array) -> jax.Array:
             - 10.0 * jnp.cos(jnp.float32(2.0 * jnp.pi) * rot) + 10.0)
 
 
+def block_sums(x: jax.Array, width: int) -> jax.Array:
+    """(n, C) -> (n, C // width) sums over consecutive ``width``-column
+    blocks, as a matmul with a 0/1 block-membership matrix. Mosaic refuses
+    the (n, C) -> (n, C // width, width) reshape; on 0/1 genes the matmul
+    sums small integers, which is exact at any MXU precision."""
+    C = x.shape[1]
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C // width), 0)
+    start = jax.lax.broadcasted_iota(jnp.int32, (C, C // width), 1) * width
+    member = ((col >= start) & (col < start + width)).astype(jnp.float32)
+    return jnp.dot(x, member, preferred_element_type=jnp.float32)
+
+
 def fused_fitness(popf: jax.Array, spec: Dict[str, Any],
                   consts: Optional[Dict[str, Any]] = None) -> jax.Array:
     """In-VMEM fitness of the freshly built population — the optional fused
@@ -338,12 +413,12 @@ def fused_fitness(popf: jax.Array, spec: Dict[str, Any],
     if kind == "trap":
         a, b, z, l = (float(spec["a"]), float(spec["b"]), float(spec["z"]),
                       int(spec["l"]))
-        u = popf.reshape(n, -1, l).sum(axis=-1)
+        u = block_sums(popf, l)
         f = jnp.where(u <= z, a * (z - u) / z, b * (u - z) / (l - z))
-        return f.sum(axis=-1)
+        return ordered_sum(f)
     if kind == "royal_road":
         r = int(spec["r"])
-        u = popf.reshape(n, -1, r).sum(axis=-1)
+        u = block_sums(popf, r)
         return jnp.float32(r) * (u >= r - 0.5).astype(jnp.float32).sum(-1)
     if kind == "onemax":
         return popf.sum(axis=-1)
@@ -368,12 +443,13 @@ def fused_fitness(popf: jax.Array, spec: Dict[str, Any],
         # z[:, perm] as a one-hot matmul: P[r, c] = (perm[c] == r)
         ponehot = (jnp.asarray(perm, jnp.int32)[None, :]
                    == _lanes(L)[:, None]).astype(jnp.float32)
-        zp = jnp.dot(z, ponehot, preferred_element_type=jnp.float32)
+        zp = onehot_matmul(z, ponehot, split="a")
         total = jnp.zeros((n,), jnp.float32)
         for g in range(n_groups):
             rot = jnp.dot(zp[:, g * m:(g + 1) * m],
                           M[g].astype(jnp.float32),
-                          preferred_element_type=jnp.float32)
+                          preferred_element_type=jnp.float32,
+                          precision=HIGHEST)
             total = total + rastrigin_terms(rot).sum(axis=-1)
         return -total
     raise ValueError(f"unknown fused eval {kind!r}")
@@ -422,10 +498,10 @@ def generation_math(k0: jax.Array, k1: jax.Array, pop: jax.Array,
     assert L == spec.length, (L, spec.length)
     plan = selection_plan(k0, k1, fitness, pop_size, spec, n)
     popf = pop.astype(jnp.float32)
-    pa = _gather_rows(popf, plan.idx_a)
-    pb = _gather_rows(popf, plan.idx_b)
-    kids = child_tile_math(k0, k1, pa, pb, plan.cut1, plan.cut2, plan.gate,
-                           spec, 0, 0)
+    pa = _gather_rows(popf, plan.idx_a, gather_split(spec))
+    pb = _gather_rows(popf, plan.idx_b, gather_split(spec))
+    kids = child_tile_math(k0, k1, pa, pb, plan.cut1[:, None],
+                           plan.cut2[:, None], plan.gate[:, None], spec, 0, 0)
     new_pop = kids.astype(pop.dtype)
     if spec.fused_eval is not None:
         return new_pop, fused_fitness(kids, spec.eval_spec, consts)

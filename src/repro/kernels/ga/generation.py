@@ -27,32 +27,27 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .common import GenerationSpec, generation_math, spec_needs_consts
 
 
-def _generation_kernel(seed_ref, size_ref, pop_ref, fit_ref, out_ref, *,
-                       spec: GenerationSpec):
-    k0 = seed_ref[0]
-    k1 = seed_ref[1]
-    out_ref[...] = generation_math(k0, k1, pop_ref[...], fit_ref[...],
-                                   size_ref[0], spec)
-
-
-def _generation_eval_kernel(seed_ref, size_ref, pop_ref, fit_ref, *refs,
-                            spec: GenerationSpec, with_consts: bool):
+def _generation_kernel(seed_ref, size_ref, pop_ref, fit_ref, *refs,
+                       spec: GenerationSpec, with_consts: bool):
     if with_consts:
-        o_ref, perm_ref, m_ref, out_ref, fit_out_ref = refs
+        o_ref, perm_ref, m_ref, *outs = refs
         consts = {"o": o_ref[...], "perm": perm_ref[...], "M": m_ref[...]}
     else:
-        out_ref, fit_out_ref = refs
-        consts = None
-    k0 = seed_ref[0]
-    k1 = seed_ref[1]
-    new_pop, new_fit = generation_math(k0, k1, pop_ref[...], fit_ref[...],
-                                       size_ref[0], spec, consts=consts)
-    out_ref[...] = new_pop
-    fit_out_ref[...] = new_fit
+        outs, consts = refs, None
+    n = pop_ref.shape[0]
+    out = generation_math(seed_ref[0, 0], seed_ref[0, 1], pop_ref[...],
+                          fit_ref[...].reshape(n), size_ref[0, 0], spec,
+                          consts=consts)
+    if spec.fused_eval is None:
+        outs[0][...] = out
+    else:
+        outs[0][...] = out[0]
+        outs[1][...] = out[1].reshape(n, 1)
 
 
 def generation_kernel(seed: jax.Array, size: jax.Array, pop: jax.Array,
@@ -62,29 +57,36 @@ def generation_kernel(seed: jax.Array, size: jax.Array, pop: jax.Array,
     fitness: (max_pop,) f32 -> new pop (max_pop, L) [+ (max_pop,) f32 raw
     fitness when ``spec.fused_eval`` is set]. Fused evals with array
     constants (f15) take them via ``consts`` — the arrays ride into VMEM as
-    extra kernel operands."""
+    extra kernel operands.
+
+    Every operand is at least 2-D, so that under ``jax.vmap`` (one grid
+    step per island) each block still spans its array's last two dims, as
+    Mosaic requires: the seed and size ride in SMEM as (1, 2) and (1, 1),
+    fitness goes in as a (1, max_pop) row and comes out as a column."""
     n, L = pop.shape
-    if spec.fused_eval is not None:
-        with_consts = spec_needs_consts(spec)
-        kernel = functools.partial(_generation_eval_kernel, spec=spec,
-                                   with_consts=with_consts)
-        operands = [seed, size, pop, fitness]
-        if with_consts:
-            if consts is None:
-                raise ValueError(f"fused eval {spec.eval_spec['eval']!r} "
-                                 "needs problem consts")
-            operands += [jnp.asarray(consts["o"], jnp.float32),
-                         jnp.asarray(consts["perm"], jnp.int32),
-                         jnp.asarray(consts["M"], jnp.float32)]
+    with_consts = spec_needs_consts(spec)
+    kernel = functools.partial(_generation_kernel, spec=spec,
+                               with_consts=with_consts)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    operands = [seed.reshape(1, 2), size.reshape(1, 1), pop,
+                fitness.reshape(1, n)]
+    if with_consts:
+        if consts is None:
+            raise ValueError(f"fused eval {spec.eval_spec['eval']!r} "
+                             "needs problem consts")
+        operands += [jnp.asarray(consts["o"], jnp.float32),
+                     jnp.asarray(consts["perm"], jnp.int32),
+                     jnp.asarray(consts["M"], jnp.float32)]
+    out_shape = jax.ShapeDtypeStruct((n, L), pop.dtype)
+    if spec.fused_eval is None:
         return pl.pallas_call(
-            kernel,
-            out_shape=(jax.ShapeDtypeStruct((n, L), pop.dtype),
-                       jax.ShapeDtypeStruct((n,), jnp.float32)),
-            interpret=interpret,
-        )(*operands)
-    kernel = functools.partial(_generation_kernel, spec=spec)
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((n, L), pop.dtype),
-        interpret=interpret,
-    )(seed, size, pop, fitness)
+            kernel, out_shape=out_shape,
+            in_specs=[smem, smem] + [vmem] * (len(operands) - 2),
+            out_specs=vmem, interpret=interpret)(*operands)
+    new_pop, fit = pl.pallas_call(
+        kernel, out_shape=(out_shape,
+                           jax.ShapeDtypeStruct((n, 1), jnp.float32)),
+        in_specs=[smem, smem] + [vmem] * (len(operands) - 2),
+        out_specs=(vmem, vmem), interpret=interpret)(*operands)
+    return new_pop, fit.reshape(n)

@@ -7,7 +7,7 @@ and registers the built-in impls:
 
 * ``jnp``          — the classic :func:`repro.core.ga.next_generation_jnp`
                      path (four ops, jax.random streams).
-* ``pallas``       — the fused VMEM megakernel (interpret-mode off-TPU).
+* ``pallas``       — the fused VMEM megakernel (interpret mode on the CPU).
                      Auto-routes to the tiled engine once the untiled VMEM
                      estimate exceeds :data:`VMEM_BUDGET_BYTES`, so callers
                      never fall off a VMEM cliff by growing the population.
@@ -30,7 +30,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from .. import on_tpu
+from .. import interpret_mode
 from . import autotune as _autotune
 from . import generation as _k
 from . import ref as _ref
@@ -111,7 +111,7 @@ def _tiles(pop, genome, tile_pop, tile_len):
 def generation(rng, pop, fitness, pop_size, cfg, genome, *,
                interpret: Optional[bool] = None, consts=None):
     spec = make_spec(cfg, genome)
-    interpret = (not on_tpu()) if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     if untiled_vmem_bytes(*pop.shape, spec) > VMEM_BUDGET_BYTES:
         tp, tl = _tiles(pop, genome, None, None)
         return _tiling.generation_tiled(_seed_words(rng), _size_vec(pop_size),
@@ -128,7 +128,7 @@ def generation_tiled(rng, pop, fitness, pop_size, cfg, genome, *,
                      tile_pop: Optional[int] = None,
                      tile_len: Optional[int] = None, consts=None):
     spec = make_spec(cfg, genome)
-    interpret = (not on_tpu()) if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     tp, tl = _tiles(pop, genome, tile_pop, tile_len)
     return _tiling.generation_tiled(_seed_words(rng), _size_vec(pop_size),
                                     pop, fitness, spec, tile_pop=tp,
@@ -151,7 +151,7 @@ def generation_ref(rng, pop, fitness, pop_size, cfg, genome, *, consts=None):
 def generation_eval(rng, pop, fitness, pop_size, cfg, genome, fused, *,
                     interpret: Optional[bool] = None, consts=None):
     spec = make_spec(cfg, genome, fused=fused)
-    interpret = (not on_tpu()) if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     if untiled_vmem_bytes(*pop.shape, spec) > VMEM_BUDGET_BYTES:
         tp, tl = _tiles(pop, genome, None, None)
         return _tiling.generation_tiled(_seed_words(rng), _size_vec(pop_size),
@@ -170,7 +170,7 @@ def generation_eval_tiled(rng, pop, fitness, pop_size, cfg, genome, fused, *,
                           tile_pop: Optional[int] = None,
                           tile_len: Optional[int] = None, consts=None):
     spec = make_spec(cfg, genome, fused=fused)
-    interpret = (not on_tpu()) if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     tp, tl = _tiles(pop, genome, tile_pop, tile_len)
     return _tiling.generation_tiled(_seed_words(rng), _size_vec(pop_size),
                                     pop, fitness, spec, tile_pop=tp,

@@ -103,10 +103,16 @@ def random_bits(k0: jax.Array, k1: jax.Array, shape: Tuple[int, int],
     return out
 
 
+def _unit24(bits: jax.Array) -> jax.Array:
+    """Top 24 bits as f32 in [0, 1). The detour through int32 is exact (24
+    bits fit) and is what Mosaic lowers: it refuses uint32 -> float32."""
+    return ((bits >> u32(8)).astype(jnp.int32).astype(jnp.float32)
+            * jnp.float32(1.0 / (1 << 24)))
+
+
 def uniform(k0, k1, shape, salt, offset=(0, 0), row_stride=None) -> jax.Array:
     """f32 uniforms in [0, 1): top 24 bits scaled — exact in float32."""
-    bits = random_bits(k0, k1, shape, salt, offset, row_stride)
-    return (bits >> u32(8)).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    return _unit24(random_bits(k0, k1, shape, salt, offset, row_stride))
 
 
 def randint(k0, k1, shape, maxval, salt, offset=(0, 0),
@@ -126,8 +132,7 @@ def normal(k0, k1, shape, salt, offset=(0, 0), row_stride=None) -> jax.Array:
     """Standard normals via Box-Muller (both counter words of one call)."""
     cnt = _counters(shape, offset, row_stride)
     b0, b1 = threefry2x32(k0, k1, cnt, jnp.full(shape, salt, u32))
-    scale = jnp.float32(1.0 / (1 << 24))
-    u1 = (b0 >> u32(8)).astype(jnp.float32) * scale
-    u2 = (b1 >> u32(8)).astype(jnp.float32) * scale
+    u1 = _unit24(b0)
+    u2 = _unit24(b1)
     r = jnp.sqrt(-2.0 * jnp.log(1.0 - u1))  # 1-u1 in (0,1]: log is finite
     return r * jnp.cos(jnp.float32(2.0 * jnp.pi) * u2)

@@ -14,7 +14,7 @@ Maps ``(op, genome_kind, impl)`` to a callable. Ops:
 
 Built-in impls (registered on import of :mod:`repro.kernels.ga`):
 ``jnp`` (the classic ``core.ga`` path), ``pallas`` (the fused VMEM
-megakernel, interpret-mode off-TPU; auto-routes to the tiled engine
+megakernel, interpret mode on the CPU; auto-routes to the tiled engine
 beyond a VMEM estimate), ``pallas_tiled`` (the grid-tiled streaming
 megakernel, forced), ``pallas_ref`` (the pure-jnp oracle of both — same
 counter RNG, same math; bit-exact vs ``pallas``/``pallas_tiled`` in

@@ -53,8 +53,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import (GenerationSpec, child_tile_math, selection_plan,
-                     separable_fused_tile, spec_needs_consts)
+from .common import (GenerationSpec, child_tile_math, gather_split,
+                     onehot_matmul, selection_plan, separable_fused_tile,
+                     spec_needs_consts)
 
 DEFAULT_TILE_POP = 256
 DEFAULT_TILE_LEN = 512
@@ -65,10 +66,11 @@ def _pad_up(x: int, to: int) -> int:
 
 
 def _onehot_block(idx: jax.Array, k, tk: int) -> jax.Array:
-    """(TP, TK) f32 one-hot of per-row source indices vs source block k."""
+    """(TP, TK) f32 one-hot of per-row source indices (a (TP, 1) column)
+    vs source block k."""
     lanes = (jnp.asarray(k, jnp.int32) * tk
              + jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1))
-    return (idx[:, None] == lanes).astype(jnp.float32)
+    return (idx == lanes).astype(jnp.float32)
 
 
 def _tiled_kernel(seed_ref, idxa_ref, idxb_ref, c1_ref, c2_ref, gate_ref,
@@ -85,10 +87,9 @@ def _tiled_kernel(seed_ref, idxa_ref, idxb_ref, c1_ref, c2_ref, gate_ref,
     nk = pl.num_programs(2)
 
     popb = pop_ref[...].astype(jnp.float32)          # (TP, TL) source block
-    part_a = jnp.dot(_onehot_block(idxa_ref[...], k, tp), popb,
-                     preferred_element_type=jnp.float32)
-    part_b = jnp.dot(_onehot_block(idxb_ref[...], k, tp), popb,
-                     preferred_element_type=jnp.float32)
+    split = gather_split(spec)
+    part_a = onehot_matmul(_onehot_block(idxa_ref[...], k, tp), popb, split)
+    part_b = onehot_matmul(_onehot_block(idxb_ref[...], k, tp), popb, split)
 
     @pl.when(k == 0)
     def _init():
@@ -102,14 +103,14 @@ def _tiled_kernel(seed_ref, idxa_ref, idxb_ref, c1_ref, c2_ref, gate_ref,
 
     @pl.when(k == nk - 1)
     def _emit():
-        kids = child_tile_math(seed_ref[0], seed_ref[1], pa_acc[...],
+        kids = child_tile_math(seed_ref[0, 0], seed_ref[0, 1], pa_acc[...],
                                pb_acc[...], c1_ref[...], c2_ref[...],
                                gate_ref[...], spec,
                                row0=i * tp, col0=j * tl)
         out_ref[...] = kids.astype(out_ref.dtype)
         if fit_ref is not None:
             part = separable_fused_tile(kids, spec.eval_spec, j * tl,
-                                        spec.length)
+                                        spec.length)[:, None]
 
             @pl.when(j == 0)
             def _fit_init():
@@ -155,7 +156,8 @@ def generation_tiled(seed: jax.Array, size: jax.Array, pop: jax.Array,
         if consts is None:
             raise ValueError("fused f15 evaluation needs problem consts")
         from ..rastrigin import ops as f15_ops
-        fit = -f15_ops.f15(consts, new_pop.astype(jnp.float32))
+        fit = -f15_ops.f15(consts, new_pop.astype(jnp.float32),
+                           interpret=interpret)
         return new_pop, fit
 
     fused = fused_spec is not None
@@ -168,15 +170,17 @@ def generation_tiled(seed: jax.Array, size: jax.Array, pop: jax.Array,
     plan = selection_plan(k0, k1, fitness, size[0], spec, n)
     pad_r, pad_c = np_ - n, lp - L
     popp = jnp.pad(pop, ((0, pad_r), (0, pad_c)))
-    pvec = lambda v: jnp.pad(v, (0, pad_r))  # noqa: E731
+    # plan rows and fused fitness travel as (np_, 1) columns: Mosaic
+    # refuses 1-D (tp,) row blocks whose tiling differs from XLA's
+    pcol = lambda v: jnp.pad(v, (0, pad_r))[:, None]  # noqa: E731
 
     ni, nj, nk = np_ // tp, lp // tl, np_ // tp
     grid = (ni, nj, nk)
-    row_spec = pl.BlockSpec((tp,), lambda i, j, k: (i,))
+    row_spec = pl.BlockSpec((tp, 1), lambda i, j, k: (i, 0))
     out_shape = [jax.ShapeDtypeStruct((np_, lp), pop.dtype)]
     out_specs = [pl.BlockSpec((tp, tl), lambda i, j, k: (i, j))]
     if fused:
-        out_shape.append(jax.ShapeDtypeStruct((np_,), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((np_, 1), jnp.float32))
         out_specs.append(row_spec)
 
     kernel = functools.partial(_tiled_kernel, spec=spec, tp=tp, tl=tl,
@@ -185,7 +189,7 @@ def generation_tiled(seed: jax.Array, size: jax.Array, pop: jax.Array,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((2,), lambda i, j, k: (0,)),      # seed
+            pl.BlockSpec(memory_space=pltpu.SMEM),         # (1, 2) seed
             row_spec, row_spec, row_spec, row_spec, row_spec,
             pl.BlockSpec((tp, tl), lambda i, j, k: (k, j)),  # pop source
         ],
@@ -194,12 +198,12 @@ def generation_tiled(seed: jax.Array, size: jax.Array, pop: jax.Array,
         scratch_shapes=[pltpu.VMEM((tp, tl), jnp.float32),
                         pltpu.VMEM((tp, tl), jnp.float32)],
         interpret=interpret,
-    )(seed, pvec(plan.idx_a), pvec(plan.idx_b), pvec(plan.cut1),
-      pvec(plan.cut2), pvec(plan.gate), popp)
+    )(seed.reshape(1, 2), pcol(plan.idx_a), pcol(plan.idx_b),
+      pcol(plan.cut1), pcol(plan.cut2), pcol(plan.gate), popp)
 
     if fused:
         new_pop, fit = out
-        return new_pop[:n, :L], fit[:n]
+        return new_pop[:n, :L], fit[:n, 0]
     return out[:n, :L]
 
 

@@ -3,12 +3,12 @@ for the rotate+reduce hot loop."""
 from __future__ import annotations
 
 from functools import partial
-from typing import Dict
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
-from .. import on_tpu
+from .. import interpret_mode
 from . import rastrigin as _k
 from . import ref as _ref
 
@@ -19,11 +19,13 @@ def _pad_up(x: int, to: int) -> int:
     return -(-x // to) * to
 
 
-@partial(jax.jit, static_argnames=("pop_block", "force_ref", "lane"))
+@partial(jax.jit, static_argnames=("pop_block", "force_ref", "lane",
+                                   "interpret"))
 def f15(consts: Dict[str, jax.Array], pop: jax.Array, *,
         pop_block: int = _k.POP_BLOCK, force_ref: bool = False,
-        lane: int = MXU_LANE) -> jax.Array:
-    """CEC2010-F15 fitness (minimization value). pop: (N, D) f32 -> (N,)."""
+        lane: int = MXU_LANE, interpret: Optional[bool] = None) -> jax.Array:
+    """CEC2010-F15 fitness (minimization value). pop: (N, D) f32 -> (N,).
+    ``interpret`` defaults to :func:`repro.kernels.interpret_mode`."""
     if force_ref:
         return _ref.f15(consts, pop)
     o, perm, M = consts["o"], consts["perm"], consts["M"]
@@ -37,5 +39,6 @@ def f15(consts: Dict[str, jax.Array], pop: jax.Array, *,
     z = jnp.pad(z.astype(jnp.float32), ((0, pad_n), (0, 0), (0, mp - m)))
     Mp = jnp.pad(M.astype(jnp.float32), ((0, 0), (0, mp - m), (0, mp - m)))
     out = _k.f15_kernel(z.reshape(n + pad_n, G * mp), Mp,
-                        interpret=not on_tpu(), pop_block=pb)
+                        interpret=(interpret_mode() if interpret is None
+                                   else interpret), pop_block=pb)
     return out[:n]

@@ -13,7 +13,8 @@ group grid dimension.
 Padding is exact: padded coordinates are zero, and rastrigin(0) = 0, so
 padded lanes contribute nothing.
 
-Grid: (N/POP_BLOCK, G) — output block revisited across g (accumulation).
+Grid: (N/POP_BLOCK, G) — the (POP_BLOCK, 1) output column is revisited
+across g (accumulation).
 VMEM per step: POP_BLOCK*m̂ (z tile) + m̂*m̂ (M_g) + POP_BLOCK*m̂ (rotated)
 ≈ 256*128*4B * 2 + 64KB ≈ 320 KiB — well within a v5e core's 128 MiB VMEM
 budget with double buffering.
@@ -34,9 +35,10 @@ def _f15_kernel(z_ref, m_ref, out_ref):
     g = pl.program_id(1)
     z = z_ref[...]                       # (PB, m̂) f32, pre-shifted+permuted
     M = m_ref[0]                         # (m̂, m̂) f32, zero-padded
-    rot = jnp.dot(z, M, preferred_element_type=jnp.float32)
+    rot = jnp.dot(z, M, preferred_element_type=jnp.float32,
+                  precision=jax.lax.Precision.HIGHEST)
     r = rot * rot - 10.0 * jnp.cos(TWO_PI * rot) + 10.0
-    part = r.sum(axis=-1)                # (PB,)
+    part = r.sum(axis=-1, keepdims=True)  # (PB, 1)
 
     @pl.when(g == 0)
     def _init():
@@ -62,7 +64,7 @@ def f15_kernel(zp: jax.Array, M: jax.Array, *, interpret: bool = False,
             pl.BlockSpec((pop_block, mp), lambda i, g: (i, g)),
             pl.BlockSpec((1, mp, mp), lambda i, g: (g, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((pop_block,), lambda i, g: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
+        out_specs=pl.BlockSpec((pop_block, 1), lambda i, g: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
         interpret=interpret,
-    )(zp, M.reshape(G, mp, mp))
+    )(zp, M.reshape(G, mp, mp))[:, 0]

@@ -6,7 +6,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from .. import on_tpu
+from .. import interpret_mode
 from . import ref as _ref
 from . import rwkv6 as _k
 
@@ -39,6 +39,6 @@ def wkv(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
                           ).reshape(B * H, hd)
     s0 = state.reshape(B * H, hd, hd).astype(jnp.float32)
     y, sout = _k.wkv_kernel(rb, kb, vb, wb, ub, s0, chunk=chunk,
-                            interpret=not on_tpu())
+                            interpret=interpret_mode())
     y = jnp.moveaxis(y.reshape(B, H, Sp, hd), 1, 2)[:, :S]
     return y, sout.reshape(B, H, hd, hd)

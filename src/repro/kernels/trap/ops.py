@@ -1,4 +1,4 @@
-"""Public wrapper: pad-to-block, dispatch kernel (interpret off-TPU)."""
+"""Public wrapper: pad to the block, dispatch the Pallas kernel."""
 from __future__ import annotations
 
 from functools import partial
@@ -7,7 +7,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from .. import on_tpu
+from .. import interpret_mode
 from . import trap as _k
 from . import ref as _ref
 
@@ -31,5 +31,5 @@ def trap_fitness(consts: Dict[str, float], pop: jax.Array, *, n_traps: int,
     if pad:
         pop = jnp.pad(pop, ((0, pad), (0, 0)))
     out = _k.trap_fitness_kernel(pop, n_traps=n_traps, l=l, a=a, b=b, z=z,
-                                 interpret=not on_tpu(), pop_block=pb)
+                                 interpret=interpret_mode(), pop_block=pb)
     return out[:n]

@@ -4,6 +4,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .. import ordered_sum
+
 
 def trap_fitness(pop: jax.Array, *, n_traps: int, l: int, a: float, b: float,
                  z: float) -> jax.Array:
@@ -11,4 +13,4 @@ def trap_fitness(pop: jax.Array, *, n_traps: int, l: int, a: float, b: float,
     blocks = pop.reshape(n, n_traps, l).astype(jnp.float32)
     u = blocks.sum(-1)
     f = jnp.where(u <= z, a * (z - u) / z, b * (u - z) / (l - z))
-    return f.sum(-1)
+    return ordered_sum(f)

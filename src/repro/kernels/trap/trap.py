@@ -2,8 +2,9 @@
 
 The EA hot loop evaluates the whole (padded) population every generation.
 One grid step scores a (POP_BLOCK, n_traps*l) tile held in VMEM: bits are
-summed per l-wide trap block (VPU reduction over a reshaped view) and the
-piecewise-linear trap value is reduced over traps. Population tiles are
+summed per l-wide trap block (a matmul with a 0/1 block-membership matrix,
+exact on bits) and the piecewise-linear trap value is reduced over traps
+into a (POP_BLOCK, 1) column. Population tiles are
 independent -> embarrassingly parallel grid.
 
 Layout: chromosomes are int8 in HBM; a tile is (POP_BLOCK, L) int8 = e.g.
@@ -18,16 +19,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import ordered_sum
+from ..ga.common import block_sums
+
 POP_BLOCK = 256
 
 
 def _trap_kernel(pop_ref, out_ref, *, n_traps: int, l: int, a: float,
                  b: float, z: float):
     bits = pop_ref[...].astype(jnp.float32)            # (PB, n_traps*l)
-    pb = bits.shape[0]
-    u = bits.reshape(pb, n_traps, l).sum(axis=-1)      # (PB, n_traps)
+    u = block_sums(bits, l)                            # (PB, n_traps)
     f = jnp.where(u <= z, a * (z - u) / z, b * (u - z) / (l - z))
-    out_ref[...] = f.sum(axis=-1)                      # (PB,)
+    out_ref[...] = ordered_sum(f)[:, None]             # (PB, 1)
 
 
 def trap_fitness_kernel(pop: jax.Array, *, n_traps: int, l: int, a: float,
@@ -44,7 +47,7 @@ def trap_fitness_kernel(pop: jax.Array, *, n_traps: int, l: int, a: float,
         kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((pop_block, L), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((pop_block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
+        out_specs=pl.BlockSpec((pop_block, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
         interpret=interpret,
-    )(pop)
+    )(pop)[:, 0]

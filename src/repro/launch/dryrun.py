@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import set_mesh
 
 from repro.configs import ARCHS, get_config
 from repro.launch import shardings as sh
@@ -212,7 +211,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
     t0 = time.perf_counter()
     fn, args, mesh, model, _ = build_cell(arch, shape, multi_pod,
                                           accum=accum, sharding=sharding)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*args)
         t1 = time.perf_counter()
         compiled = lowered.compile()
@@ -248,7 +247,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
                                                  unroll=u, accum=1,
                                                  sharding=sharding,
                                                  cost_mode=True)
-                with set_mesh(mesh):
+                with jax.set_mesh(mesh):
                     cu = fnu.lower(*argsu).compile()
                 fl, by = cost_of(cu)
                 co = collective_bytes(cu.as_text())

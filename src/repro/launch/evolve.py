@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import ARCHS, get_config
 from repro.core import (AcceptanceConfig, AsyncConfig, AsyncHostBridge,
                         EAConfig, HostBridge, MigrationConfig, PoolServer,
@@ -270,7 +271,7 @@ def main(argv=None):
                          "registry): jnp = classic four-op jax.random "
                          "path; pallas = fused selection->crossover->"
                          "mutation[->fitness] VMEM megakernel with "
-                         "on-chip counter RNG (interpret-mode off-TPU); "
+                         "on-chip counter RNG (interpret mode on the CPU); "
                          "pallas_ref = the megakernel's pure-jnp oracle. "
                          "Benchmark the impls against each other with "
                          "`python -m benchmarks.speed_baseline`, which "
@@ -284,6 +285,11 @@ def main(argv=None):
     pbt.add_argument("--epochs", type=int, default=5)
     pbt.add_argument("--steps-per-epoch", type=int, default=20)
     args = ap.parse_args(argv)
+    cache_dir = use_compile_cache()
+    devs = jax.devices()
+    print(f"jax devices: platform={devs[0].platform} "
+          f"kind={devs[0].device_kind} count={len(devs)} "
+          f"compile_cache={cache_dir}", flush=True)
     if args.mode == "ea":
         acfg = AsyncConfig(min_rate=args.min_rate, max_rate=args.max_rate,
                            staleness=args.staleness,

@@ -28,7 +28,7 @@ def make_host_mesh(n: int | None = None, axis: str = "islands") -> Mesh:
     """1-D mesh over however many (possibly fake) devices exist — used by
     the sharded evolution runner and small-mesh tests."""
     devs = jax.devices()[: (n or len(jax.devices()))]
-    return make_mesh((len(devs),), (axis,))
+    return make_mesh((len(devs),), (axis,), devices=devs)
 
 
 def dp_axes(mesh: Mesh) -> Tuple[str, ...]:

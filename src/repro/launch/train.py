@@ -21,7 +21,6 @@ import numpy as np
 from jax.sharding import NamedSharding
 
 from repro.checkpoint import Checkpointer, latest_step, restore
-from repro.compat import set_mesh
 from repro.configs import ARCHS, get_config
 from repro.data import ShardedLoader, SyntheticLM
 from repro.launch import shardings as sh
@@ -83,13 +82,13 @@ def train(arch: str, smoke: bool = True, steps: int = 100, batch: int = 8,
         if verbose:
             print(f"resumed from step {start}")
     else:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = init_train_state(model, jax.random.key(seed))
             state = jax.device_put(state, state_shard)
 
     losses = []
     t0 = time.perf_counter()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for i in range(start, steps):
             batch_i = loader.next()
             state, metrics = jstep(state, batch_i)

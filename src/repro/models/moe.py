@@ -107,8 +107,6 @@ def _apply_ep(p: Dict, cfg: ModelConfig, x: Array,
     """
     from jax.sharding import PartitionSpec as SP
 
-    from repro.compat import shard_map
-
     n_ep = mesh.shape["model"]
     e_local = cfg.n_experts // n_ep
 
@@ -167,14 +165,14 @@ def _apply_ep(p: Dict, cfg: ModelConfig, x: Array,
         return y.reshape(B, S, d), aux
 
     w_names = ("wg", "wu", "wd") if "wg" in p else ("wu", "wd")
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(SP("data", None, None), SP(None, None),
                   *[SP("model", None, None)] * len(w_names)),
         out_specs=(SP("data", None, None),
                    {"load_balance": SP(), "router_z": SP(),
                     "dropped_frac": SP()}),
-        check=False)
+        check_vma=False)
     return fn(x, p["router"], *[p[n] for n in w_names])
 
 

@@ -24,7 +24,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
 
 
 def _quant_int8(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -41,7 +40,7 @@ def compress_psum(grads: Any, err: Any, axis: str,
                   method: str = "int8") -> Tuple[Any, Any]:
     """Cross-pod mean of ``grads`` with error feedback. Call INSIDE a
     shard_map that has ``axis`` unreduced. Returns (synced_grads, new_err)."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
 
     def one(g, e):
         gf = g.astype(jnp.float32) + e

@@ -422,7 +422,6 @@ SPMD_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.core import (AcceptanceConfig, EAConfig, MigrationConfig,
                             make_onemax, migration)
     from repro.core import pool as pool_lib
@@ -451,12 +450,12 @@ SPMD_SCRIPT = textwrap.dedent("""
                 available=available)
             return jax.tree.map(lambda x: x[None], pool), ig, if_
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(POOL_SPEC, P(AX), P(AX), P()),
             out_specs=(PoolState(*[P(AX)] * len(PoolState._fields)),
                        P(AX), P(AX)),
-            check=False)
+            check_vma=False)
         return fn(pool_lib.pool_init(cap, GEN), g, f, jax.random.key(7))
 
     # every policy's pool replica is identical on every shard (the policy

@@ -22,7 +22,7 @@ SCRIPT = textwrap.dedent("""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
-    from repro.compat import make_mesh, set_mesh
+    from repro.compat import make_mesh
     from repro.configs import get_config
     from repro.launch import shardings as sh
     from repro.launch.dryrun import collective_bytes, cost_of
@@ -58,7 +58,7 @@ SCRIPT = textwrap.dedent("""
                                                          100))
     fn = jax.jit(step, in_shardings=(state_shard, bshard),
                  out_shardings=(state_shard, None))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = fn.lower(state, specs).compile()
     fl, by = cost_of(compiled)
     co = collective_bytes(compiled.as_text())

@@ -431,26 +431,81 @@ class TestAutotune:
         tp, tl = autotune.best_tiles(4096, 1024, "float", cache_path=path)
         assert (tp, tl) in autotune.CANDIDATES
         cache = autotune.load_cache(path)
-        assert autotune.device_kind() in cache
-        entry = cache[autotune.device_kind()]
+        key = autotune.cache_key(4096, 1024, "float")
+        assert key.startswith(autotune.device_kind()) and key in cache
+        entry = cache[key]
         assert (entry["tile_pop"], entry["tile_len"]) == (tp, tl)
         # second call is served from the cache file
         assert autotune.best_tiles(4096, 1024, "float",
                                    cache_path=path) == (tp, tl)
+        # another shape or genome kind is tuned on its own
+        autotune.best_tiles(256, 160, "binary", cache_path=path)
+        assert set(autotune.load_cache(path)) == {
+            key, autotune.cache_key(256, 160, "binary")}
         summary = autotune.cache_summary(path)
-        assert autotune.device_kind() in summary["entries"]
+        assert key in summary["entries"]
 
     def test_force_resweeps(self, tmp_path):
         from repro.kernels.ga import autotune
         path = tmp_path / "autotune_ga.json"
-        autotune.save_cache({autotune.device_kind(): {
+        autotune.save_cache({autotune.cache_key(256, 256, "float"): {
             "tile_pop": 1, "tile_len": 1, "timed": False,
-            "kind": "float", "shape": [1, 1]}}, path)
+            "kind": "float", "shape": [256, 256]}}, path)
         assert autotune.best_tiles(256, 256, "float",
                                    cache_path=path) == (1, 1)
         tp, tl = autotune.best_tiles(256, 256, "float", cache_path=path,
                                      force=True)
         assert (tp, tl) in autotune.CANDIDATES
+
+    @pytest.mark.parametrize("refuse_all", [False, True])
+    def test_sweep_reports_refused_candidates(self, tmp_path, monkeypatch,
+                                              refuse_all):
+        """A compiled sweep names every candidate the compiler refused, and
+        raises when none compiles instead of guessing a tiling."""
+        from repro.kernels.ga import autotune
+
+        def fake_time(n, L, kind, tp, tl):
+            if refuse_all or tp == 512:
+                raise ValueError(f"block ({tp}, {tl}) refused")
+            return tp * tl * 1e-9
+
+        monkeypatch.setattr(autotune, "interpret_mode", lambda: False)
+        monkeypatch.setattr(autotune, "_time_candidate", fake_time)
+        path = tmp_path / "autotune_ga.json"
+        if refuse_all:
+            with pytest.warns(UserWarning, match="refused"):
+                with pytest.raises(RuntimeError, match="no tile candidate"):
+                    autotune.best_tiles(1024, 512, "float", cache_path=path)
+            assert autotune.load_cache(path) == {}
+            return
+        with pytest.warns(UserWarning, match="512x256 refused"):
+            tp, tl = autotune.best_tiles(1024, 512, "float", cache_path=path)
+        assert (tp, tl) == (128, 256)
+        entry = autotune.load_cache(path)[
+            autotune.cache_key(1024, 512, "float")]
+        assert entry["timed"] and set(entry["refused"]) == {"512x256",
+                                                            "512x512"}
+
+
+    def test_sweep_runs_while_a_kernel_is_traced(self, tmp_path,
+                                                 monkeypatch):
+        """On the chip the sweep runs inside the caller's jit trace (the
+        tile size is a static of the kernel being traced); its timed
+        candidates must still execute. Here the candidates run in
+        interpret mode, which the CPU can execute."""
+        from repro.kernels.ga import autotune, tiling
+        real = tiling.generation_tiled
+        monkeypatch.setattr(autotune, "interpret_mode", lambda: False)
+        monkeypatch.setattr(autotune, "CANDIDATES", ((8, 16), (16, 16)))
+        monkeypatch.setattr(tiling, "generation_tiled", lambda *a, **k: real(
+            *a, **{**k, "interpret": True}))
+        path = tmp_path / "autotune_ga.json"
+        tiles = jax.jit(lambda x: x + sum(autotune.best_tiles(
+            32, 16, "float", cache_path=path)))(0)
+        entry = autotune.load_cache(path)[autotune.cache_key(32, 16,
+                                                             "float")]
+        assert entry["timed"] and set(entry["sweep_s"]) == {"8x16", "16x16"}
+        assert int(tiles) == entry["tile_pop"] + entry["tile_len"]
 
 
 # ---------------------------------------------------------------------------

@@ -287,7 +287,6 @@ SPMD_SCRIPT = textwrap.dedent("""
     import numpy as np
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.core import EAConfig, MigrationConfig, make_onemax, migration
     from repro.core import pool as pool_lib
     from repro.core.pool import NEG_INF, pool_get_random, pool_put_batch
@@ -318,12 +317,12 @@ SPMD_SCRIPT = textwrap.dedent("""
             # stack each shard's pool replica for host-side comparison
             return jax.tree.map(lambda x: x[None], pool), ig, if_
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(POOL_SPEC, P(AX), P(AX), P()),
             out_specs=(PoolState(*[P(AX)] * len(PoolState._fields)),
                        P(AX), P(AX)),
-            check=False)
+            check_vma=False)
         pool0 = pool_lib.pool_init(cap, GEN)
         return fn(pool0, g, f, jax.random.key(7))
 
@@ -376,10 +375,10 @@ SPMD_SCRIPT = textwrap.dedent("""
     def run_impl(impl):
         def body(pool, bg, bf, rng):
             return impl(pool, bg, bf, rng)
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(POOL_SPEC, P(AX), P(AX), P()),
-                       out_specs=(POOL_SPEC, P(AX), P(AX)),
-                       check=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(POOL_SPEC, P(AX), P(AX), P()),
+                           out_specs=(POOL_SPEC, P(AX), P(AX)),
+                           check_vma=False)
         return fn(pool_lib.pool_init(16, GEN), g, f, jax.random.key(11))
 
     mig = MigrationConfig(pool_capacity=16)

@@ -13,7 +13,7 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import jax, jax.numpy as jnp
-    from repro.compat import make_mesh, set_mesh
+    from repro.compat import make_mesh
     from repro.configs import get_config
     from repro.models import build_model, moe
 
@@ -28,7 +28,7 @@ SCRIPT = textwrap.dedent("""
 
     mesh = make_mesh((2, 4), ("data", "model"))
     moe.USE_EP = True
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         l_ep, metrics = jax.jit(m.loss)(p, batch)
         g = jax.jit(jax.grad(lambda pp: m.loss(pp, batch)[0]))(p)
     finite = all(bool(jnp.isfinite(x).all()) for x in jax.tree.leaves(g))

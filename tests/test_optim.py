@@ -105,7 +105,7 @@ class TestCompression:
         feedback captures the residual."""
         import os
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from repro.compat import make_mesh
         mesh = make_mesh((1,), ("pod",))
         g = {"w": jax.random.normal(jax.random.key(1), (64,))}
         e = init_error(g)
@@ -113,9 +113,9 @@ class TestCompression:
         def f(g, e):
             return compress_psum(g, e, "pod", method=method)
 
-        out, err = jax.jit(shard_map(
+        out, err = jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-            check=False))(g, e)
+            check_vma=False))(g, e)
         resid = np.asarray(out["w"]) + np.asarray(err["w"]) \
             - np.asarray(g["w"])
         np.testing.assert_allclose(resid, 0.0, atol=2e-2)

@@ -318,6 +318,27 @@ def test_async_bridge_worker_over_wire():
 
 
 # ---------------------------------------------------------------------------
+# the server leaves the accelerator alone
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("module", ["repro.server", "repro.server.service",
+                                    "repro.server.__main__"])
+def test_import_starts_no_jax_backend(module):
+    """``python -m repro.server`` runs beside a process that holds the
+    chip; a backend started at import would claim the chip itself."""
+    import subprocess
+    code = (f"import importlib; m = importlib.import_module({module!r}); "
+            "[getattr(m, a) for a in getattr(m, '__all__', ())]; "
+            "from jax._src import xla_bridge; "
+            "print(xla_bridge.backends_are_initialized())")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"))
+    out = subprocess.run([sys.executable, "-c", code], env=env, cwd=repo,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == "False"
+
+
+# ---------------------------------------------------------------------------
 # durability: spool resume (in-process; the cross-process leg lives in
 # scripts/kill_resume_smoke.py leg 4)
 # ---------------------------------------------------------------------------

@@ -14,8 +14,8 @@ from repro.models import build_model
 def mesh16():
     # fake (data=1, model=1) won't exercise divisibility; build an abstract
     # 16x16 mesh from the single CPU device via AbstractMesh
-    from repro.compat import abstract_mesh
-    return abstract_mesh((16, 16), ("data", "model"))
+    from jax.sharding import AbstractMesh
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def _pspecs(arch, mesh, mode):
