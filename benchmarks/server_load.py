@@ -53,15 +53,11 @@ for _p in (_SRC, _REPO):   # _REPO: `from benchmarks import hostmeta`
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-# The log-spaced mergeable latency histogram that used to live here moved
-# to repro.obs.metrics so the HTTP frontend and the timeline CLI share one
-# binning; these re-exports keep the worker subprocess and old callers
-# working (still stdlib-only — no jax in workers).
+# The log-spaced mergeable latency histogram lives in repro.obs.metrics,
+# shared with the HTTP frontend and the timeline CLI (stdlib-only — no jax
+# in workers).
 from repro.obs.metrics import (  # noqa: E402
-    _HIST_BINS, _HIST_HI_MS, _HIST_LO_MS, hist_index, hist_percentile,
-    hist_value)
-
-_ = (_HIST_LO_MS, _HIST_HI_MS, hist_value)   # legacy re-exports
+    HIST_BINS, hist_index, hist_percentile)
 
 
 # ---------------------------------------------------------------------------
@@ -107,7 +103,7 @@ async def _volunteer(cfg: Dict[str, Any], idx: int, deadline: float,
 
 
 async def _worker_main(cfg: Dict[str, Any]) -> Dict[str, Any]:
-    hist = [0] * _HIST_BINS
+    hist = [0] * HIST_BINS
     totals = {k: 0 for k in ("puts_ok", "puts_failed", "gets_ok",
                              "gets_failed", "responses", "lost",
                              "throttled")}
@@ -281,7 +277,7 @@ def run_scenario(name: str, url: Optional[str] = None,
         admin.close()
         drainer.client.close()
 
-        hist = [0] * _HIST_BINS
+        hist = [0] * HIST_BINS
         for r in results:
             for i, c in enumerate(r["hist"]):
                 hist[i] += c

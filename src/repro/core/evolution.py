@@ -450,11 +450,14 @@ def run_segments(state: ExperimentState, max_steps: int, segment_fn, *,
     """
     stats_host = state.stats if isinstance(state.stats,
                                            ExperimentStats) else None
-    for seg_len in segment_plan(int(np.asarray(state.epoch)), max_steps,
-                                snapshot_every):
-        with obs_trace.span("driver.segment", seg_len=seg_len,
-                            epoch=int(np.asarray(state.epoch))):
+    epoch = int(np.asarray(state.epoch))
+    for seg_len in segment_plan(epoch, max_steps, snapshot_every):
+        # span args are host ints: the segment's first epoch is the plan's
+        with obs_trace.span("driver.segment", seg_len=seg_len, epoch=epoch):
             state, seg_stats = segment_fn(state, seg_len)
+        with obs_trace.span("driver.wait", epoch=epoch):
+            stopped = (not w2) and bool(np.asarray(state.stopped))
+        epoch += seg_len
         if return_stats:
             seg_np = jax.tree.map(np.asarray, seg_stats)
             stats_host = seg_np if stats_host is None else jax.tree.map(
@@ -462,7 +465,7 @@ def run_segments(state: ExperimentState, max_steps: int, segment_fn, *,
             state = state._replace(stats=stats_host)
         if checkpointer is not None:
             checkpointer.save_async(int(np.asarray(state.epoch)), state)
-        if (not w2) and bool(np.asarray(state.stopped)):
+        if stopped:
             break
     if return_stats and stats_host is not None:
         rows = int(stats_host.epoch.shape[0])
@@ -507,36 +510,39 @@ def run_fused(problem: Problem,
     seeds fresh islands from the pool under new (never recycled) uuids.
     """
     rng = jax.random.key(0) if rng is None else rng
-    k_init, k_loop = jax.random.split(rng)
     ckpt = resolve_checkpointer(snapshot_dir, checkpointer, snapshot_keep)
+    if resume and ckpt is None:
+        raise ValueError("resume=True needs snapshot_dir or checkpointer")
 
-    state = None
-    if resume:
-        if ckpt is None:
-            raise ValueError("resume=True needs snapshot_dir or checkpointer")
-        template = ExperimentState(
-            islands=island_lib.init_islands(k_init, n_islands, problem, cfg),
-            pool=pool_lib.pool_init(mig.pool_capacity, problem.genome),
-            # structure-only: restore replaces every leaf, including the key
-            astate=(), key=jax.random.key(0), epoch=jnp.int32(0),
-            stopped=jnp.asarray(False),
-            stats=empty_stats() if return_stats else (),
-            next_uuid=jnp.int32(n_islands),
-            obs=obs_lib.init_obs(n_islands) if return_obs else ())
-        state = restore_experiment_state(ckpt, template)
-        if int(state.islands.pop.shape[0]) != n_islands:
-            from repro.runtime import elastic as elastic_lib  # deferred: avoid cycle
-            state = elastic_lib.resize_experiment(state, n_islands, problem,
-                                                  cfg)
-    if state is None:
-        islands0 = island_lib.init_islands(k_init, n_islands, problem, cfg)
-        pool0 = pool_lib.pool_init(mig.pool_capacity, problem.genome)
-        state = ExperimentState(
-            islands=islands0, pool=pool0, astate=(), key=k_loop,
-            epoch=jnp.int32(0), stopped=jnp.asarray(False),
-            stats=empty_stats() if return_stats else (),
-            next_uuid=jnp.int32(n_islands),
-            obs=obs_lib.init_obs(n_islands) if return_obs else ())
+    with obs_trace.span("driver.init", n_islands=n_islands, resume=resume):
+        k_init, k_loop = jax.random.split(rng)
+        if resume:
+            template = ExperimentState(
+                islands=island_lib.init_islands(k_init, n_islands, problem,
+                                                cfg),
+                pool=pool_lib.pool_init(mig.pool_capacity, problem.genome),
+                # structure-only: restore replaces every leaf, including
+                # the key
+                astate=(), key=jax.random.key(0), epoch=jnp.int32(0),
+                stopped=jnp.asarray(False),
+                stats=empty_stats() if return_stats else (),
+                next_uuid=jnp.int32(n_islands),
+                obs=obs_lib.init_obs(n_islands) if return_obs else ())
+            state = restore_experiment_state(ckpt, template)
+            if int(state.islands.pop.shape[0]) != n_islands:
+                from repro.runtime import elastic as elastic_lib  # deferred: avoid cycle
+                state = elastic_lib.resize_experiment(state, n_islands,
+                                                      problem, cfg)
+        else:
+            islands0 = island_lib.init_islands(k_init, n_islands, problem,
+                                               cfg)
+            pool0 = pool_lib.pool_init(mig.pool_capacity, problem.genome)
+            state = ExperimentState(
+                islands=islands0, pool=pool0, astate=(), key=k_loop,
+                epoch=jnp.int32(0), stopped=jnp.asarray(False),
+                stats=empty_stats() if return_stats else (),
+                next_uuid=jnp.int32(n_islands),
+                obs=obs_lib.init_obs(n_islands) if return_obs else ())
 
     def segment_fn(state: ExperimentState, seg_len: int):
         run = fused_jit(

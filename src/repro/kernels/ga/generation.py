@@ -83,10 +83,12 @@ def generation_kernel(seed: jax.Array, size: jax.Array, pop: jax.Array,
         return pl.pallas_call(
             kernel, out_shape=out_shape,
             in_specs=[smem, smem] + [vmem] * (len(operands) - 2),
-            out_specs=vmem, interpret=interpret)(*operands)
+            out_specs=vmem, interpret=interpret,
+            name="gen_untiled")(*operands)
     new_pop, fit = pl.pallas_call(
         kernel, out_shape=(out_shape,
                            jax.ShapeDtypeStruct((n, 1), jnp.float32)),
         in_specs=[smem, smem] + [vmem] * (len(operands) - 2),
-        out_specs=(vmem, vmem), interpret=interpret)(*operands)
+        out_specs=(vmem, vmem), interpret=interpret,
+        name="gen_untiled")(*operands)
     return new_pop, fit.reshape(n)

@@ -198,6 +198,7 @@ def generation_tiled(seed: jax.Array, size: jax.Array, pop: jax.Array,
         scratch_shapes=[pltpu.VMEM((tp, tl), jnp.float32),
                         pltpu.VMEM((tp, tl), jnp.float32)],
         interpret=interpret,
+        name="gen_tiled",
     )(seed.reshape(1, 2), pcol(plan.idx_a), pcol(plan.idx_b),
       pcol(plan.cut1), pcol(plan.cut2), pcol(plan.gate), popp)
 

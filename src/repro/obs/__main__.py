@@ -22,9 +22,6 @@ The summary reports:
   rate per delivery, churn occupancy (down island-ticks over all
   island-ticks, when the trace pins the tick count).
 
-``--stamp BENCH_speed.json`` writes the summary under an
-``obs_timeline`` key inside an existing benchmark artifact, so a
-benchmarked run carries its own timeline next to its numbers.
 ``--merged merged_trace.json`` additionally writes the re-pid'ed merged
 Chrome trace (openable in Perfetto as one multi-process timeline).
 
@@ -143,16 +140,6 @@ def build_summary(trace_paths: List[str],
     return summary
 
 
-def stamp(bench_path: str, summary: Dict[str, Any]) -> None:
-    """Attach the timeline to an existing BENCH_*.json artifact."""
-    with open(bench_path) as fh:
-        payload = json.load(fh)
-    payload["obs_timeline"] = summary
-    with open(bench_path, "w") as fh:
-        json.dump(payload, fh, indent=2)
-        fh.write("\n")
-
-
 def _print_summary(summary: Dict[str, Any]) -> None:
     print(f"timeline: {summary['events']} spans "
           f"from {len(summary['traces'])} trace file(s)")
@@ -187,9 +174,6 @@ def main(argv=None) -> int:
                     help="write the summary as JSON")
     ap.add_argument("--merged", default=None, metavar="OUT.json",
                     help="write the re-pid'ed merged Chrome trace")
-    ap.add_argument("--stamp", default=None, metavar="BENCH.json",
-                    help="attach the summary to an existing benchmark "
-                         "artifact under an 'obs_timeline' key")
     args = ap.parse_args(argv)
 
     summary = build_summary(args.traces, args.obs)
@@ -205,9 +189,6 @@ def main(argv=None) -> int:
             json.dump(summary, fh, indent=1, sort_keys=True)
             fh.write("\n")
         print(f"wrote summary -> {args.json}")
-    if args.stamp:
-        stamp(args.stamp, summary)
-        print(f"stamped obs_timeline into {args.stamp}")
     c = summary.get("counters")
     if c and not c["ledger_balanced"]:
         print("timeline: FAIL — counter ledger does not balance")

@@ -29,11 +29,6 @@ HIST_HI_MS = 120_000.0
 _LOG_LO = math.log(HIST_LO_MS)
 _LOG_SPAN = math.log(HIST_HI_MS) - _LOG_LO
 
-# legacy spellings (benchmarks/server_load.py re-exports these)
-_HIST_BINS = HIST_BINS
-_HIST_LO_MS = HIST_LO_MS
-_HIST_HI_MS = HIST_HI_MS
-
 
 def hist_new() -> List[int]:
     """A fresh all-zero histogram."""

@@ -23,21 +23,30 @@ or the module-level tracer the runtime instruments against::
     ...
     trace.enable(None)  # or trace.disable()
 
-Instrumented code calls :func:`span` unconditionally; when tracing is
-disabled it returns a shared null context manager — one global read and
-no allocation, which is what keeps the disabled overhead unmeasurable
-(docs/observability.md records the numbers).
+Instrumented code calls :func:`span` unconditionally.  Each span also
+enters a ``jax.profiler.TraceAnnotation`` of the same name and args, so
+it lands in any running profiler session's host plane on the clock of
+the device events (``jax.profiler.trace`` around a run shows where the
+host held the chip back).  With no tracer installed and no profiler
+session, :func:`span` returns a shared null context manager: one global
+read and one ``TraceAnnotation.is_enabled()`` call, no allocation
+(docs/observability.md records the cost on the chip).
 
 Span-name scheme (dotted ``component.verb``): ``bridge.sync``,
 ``bridge.put``, ``bridge.drain``, ``checkpoint.snapshot``,
 ``checkpoint.write``, ``server.<verb>``, ``pool.<verb>``,
-``driver.segment``.  Stick to it — the timeline CLI groups by the prefix.
+``driver.init``, ``driver.segment``, ``driver.wait``.  Stick to it — the
+timeline CLI groups by the prefix.  A span's args are host values: never
+read a device array to build one.
 
-Stdlib-only: the jax-free server tier imports this module.
+Stdlib-only: the jax-free server tier imports this module.  The
+annotation class is taken from a ``jax`` the process has already
+imported; this module never imports jax itself.
 """
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
 from collections import deque
@@ -57,6 +66,25 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+
+
+class _Both:
+    """A ring-buffer span and a profiler annotation, entered together."""
+
+    __slots__ = ("_span", "_annotation")
+
+    def __init__(self, span, annotation):
+        self._span = span
+        self._annotation = annotation
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        return self._annotation.__exit__(*exc)
 
 
 class _Span:
@@ -183,13 +211,31 @@ def get_tracer() -> Optional[Tracer]:
     return _TRACER
 
 
+# jax.profiler.TraceAnnotation, once the process has imported jax
+_ANNOTATION = None
+
+
+def _annotation():
+    """The profiler's annotation class from an already-imported jax, or
+    None (no jax yet, or jax still importing)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _ANNOTATION = getattr(profiler, "TraceAnnotation", None)
+    return _ANNOTATION
+
+
 def span(name: str, **args: Any):
-    """Span against the module-level tracer; a shared null context manager
-    when tracing is disabled (the instrumentation's fast path)."""
+    """Span against the module-level tracer and any running profiler
+    session; a shared null context manager when neither records (the
+    instrumentation's fast path)."""
     t = _TRACER
+    ann = _ANNOTATION or _annotation()
+    if ann is None or not ann.is_enabled():
+        return _NULL_SPAN if t is None else t.span(name, **args)
     if t is None:
-        return _NULL_SPAN
-    return t.span(name, **args)
+        return ann(name, **args)
+    return _Both(t.span(name, **args), ann(name, **args))
 
 
 def instant(name: str, **args: Any) -> None:
