@@ -489,21 +489,18 @@ def run_fused_async(problem: Problem,
     windows, inbox), and an elastic resume gives grown islands
     churn-rejoin async rows (fresh clock, never-churn window)."""
     rng = jax.random.key(0) if rng is None else rng
-    k_init, k_loop = jax.random.split(rng)
     ckpt = evolution_lib.resolve_checkpointer(snapshot_dir, checkpointer,
                                               snapshot_keep)
 
     def fresh_state(n: int) -> ExperimentState:
-        islands0 = island_lib.init_islands(k_init, n, problem, cfg)
-        pool0 = pool_lib.pool_init(mig.pool_capacity, problem.genome)
+        # the sync driver's compiled set-up: degenerate async == run_fused
+        state, k_init = evolution_lib.fresh_experiment_state(
+            problem, cfg, mig, n, rng, return_obs)
         astate0 = init_async_state(jax.random.fold_in(k_init, 7), n,
                                    acfg, max_ticks, problem.genome)
-        return ExperimentState(
-            islands=islands0, pool=pool0, astate=astate0, key=k_loop,
-            epoch=jnp.int32(0), stopped=jnp.asarray(False),
-            stats=evolution_lib.empty_stats() if return_stats else (),
-            next_uuid=jnp.int32(n),
-            obs=obs_lib.init_obs(n) if return_obs else ())
+        return state._replace(
+            astate=astate0,
+            stats=evolution_lib.empty_stats() if return_stats else ())
 
     state = None
     if resume:

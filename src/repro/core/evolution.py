@@ -365,6 +365,35 @@ def fused_jit(problem: Problem, static_key: tuple,
     return entry[1]
 
 
+def fresh_experiment_state(problem: Problem, cfg: EAConfig,
+                           mig: MigrationConfig, n_islands: int, rng: Array,
+                           with_obs: bool = False,
+                           ) -> Tuple[ExperimentState, Array]:
+    """A fresh experiment's device state (``stats`` left ``()`` for the
+    host to fill) and ``k_init``, the key its islands were drawn from:
+    ``rng`` splits into ``(k_init, k_loop)`` and ``k_loop`` is the scan's
+    key. Built by one compiled program per problem and shape, called with
+    the experiment's key as its only argument: one launch where op-by-op
+    construction makes one per primitive. Drivers compared bit for bit
+    with :func:`run_fused` build their islands here too (compiled and
+    op-by-op float fitness can differ in the last bit)."""
+    def build(rng):
+        k_init, k_loop = jax.random.split(rng)
+        state = ExperimentState(
+            islands=island_lib.init_islands(k_init, n_islands, problem, cfg),
+            pool=pool_lib.pool_init(mig.pool_capacity, problem.genome),
+            astate=(), key=k_loop, epoch=jnp.int32(0),
+            stopped=jnp.asarray(False), stats=(),
+            next_uuid=jnp.int32(n_islands),
+            obs=obs_lib.init_obs(n_islands) if with_obs else ())
+        return state, k_init
+
+    init = fused_jit(
+        problem, ("init", cfg, mig.pool_capacity, n_islands, with_obs),
+        lambda: jax.jit(build))
+    return init(rng)
+
+
 # ---------------------------------------------------------------------------
 # Durable segmented execution: ExperimentState snapshots between sub-scans
 # ---------------------------------------------------------------------------
@@ -515,34 +544,17 @@ def run_fused(problem: Problem,
         raise ValueError("resume=True needs snapshot_dir or checkpointer")
 
     with obs_trace.span("driver.init", n_islands=n_islands, resume=resume):
-        k_init, k_loop = jax.random.split(rng)
+        state, _ = fresh_experiment_state(problem, cfg, mig, n_islands, rng,
+                                          return_obs)
+        state = state._replace(stats=empty_stats() if return_stats else ())
         if resume:
-            template = ExperimentState(
-                islands=island_lib.init_islands(k_init, n_islands, problem,
-                                                cfg),
-                pool=pool_lib.pool_init(mig.pool_capacity, problem.genome),
-                # structure-only: restore replaces every leaf, including
-                # the key
-                astate=(), key=jax.random.key(0), epoch=jnp.int32(0),
-                stopped=jnp.asarray(False),
-                stats=empty_stats() if return_stats else (),
-                next_uuid=jnp.int32(n_islands),
-                obs=obs_lib.init_obs(n_islands) if return_obs else ())
-            state = restore_experiment_state(ckpt, template)
+            # the fresh state is the structure template: restore replaces
+            # every leaf, including the key
+            state = restore_experiment_state(ckpt, state)
             if int(state.islands.pop.shape[0]) != n_islands:
                 from repro.runtime import elastic as elastic_lib  # deferred: avoid cycle
                 state = elastic_lib.resize_experiment(state, n_islands,
                                                       problem, cfg)
-        else:
-            islands0 = island_lib.init_islands(k_init, n_islands, problem,
-                                               cfg)
-            pool0 = pool_lib.pool_init(mig.pool_capacity, problem.genome)
-            state = ExperimentState(
-                islands=islands0, pool=pool0, astate=(), key=k_loop,
-                epoch=jnp.int32(0), stopped=jnp.asarray(False),
-                stats=empty_stats() if return_stats else (),
-                next_uuid=jnp.int32(n_islands),
-                obs=obs_lib.init_obs(n_islands) if return_obs else ())
 
     def segment_fn(state: ExperimentState, seg_len: int):
         run = fused_jit(
