@@ -57,7 +57,7 @@ def main(argv=None) -> int:
         mix = dict(mix, impl=control_lib.plant_bf16_gather(mix["impl"]))
     for seed in (int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()
-        entry = bench_run.make_entry(cfg, mix, seed)
+        entry = bench_run.make_entry(cfg, mix, seed, devs[:cell["chips"]])
         try:
             entry.warm_up()
             entry.window(args.seconds, lambda name: bench_run.nullcontext())

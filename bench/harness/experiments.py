@@ -1,25 +1,33 @@
-"""The ``experiments`` traffic: whole experiments back to back through the
-program's fused drivers, each from a key folded from the seed.
+"""The ``experiments`` traffic entry: whole experiments back to back
+through one of the program's drivers, each from a key folded from the
+seed.
 
-An experiment is one ``run_fused`` call from fresh islands to the first solution or to the evaluation budget, timed
-from the call to its result on the host. The window starts experiments
-until ``seconds`` have passed and ends when the last one completes, so no
-experiment is cut and the rate covers all the time of the window.
+The mix names the driver, ``drivers/<driver>.py`` beside this package
+(:func:`.spec.driver`), which is handed the problem, the EA and migration
+configurations, the epochs and the cell's chips. An experiment is one
+call of it from fresh islands to the first solution or to the evaluation
+budget, timed from the call to its result on the host. The window starts
+experiments until ``seconds`` have passed and ends when the last one
+completes, so no experiment is cut and the rate covers all the time of
+the window.
 
 After the window a sample of the experiments, drawn from the seed, and
 the slowest one are handed to :mod:`.check` with their final state.
 """
 from __future__ import annotations
 
-import dataclasses
 import math
 import time
 from contextlib import nullcontext
+from pathlib import Path
 from typing import Any, Dict, List
 
 import numpy as np
 
 from . import spec
+
+# the bench directory this file sits in: its drivers and problems
+BENCH_DIR = Path(__file__).resolve().parents[1]
 
 # keys of the warm-up experiments: outside any window's range of indices
 WARMUP_INDEX = 2**31 - 1
@@ -57,25 +65,25 @@ def kernel_shape(cfg: Dict[str, Any]) -> Dict[str, Any]:
             "group": cfg["problem"].get("group", 0)}
 
 
+def make(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int, devices):
+    return Experiments(cfg, mix, seed, devices)
+
+
 class Experiments:
     """Set-up, window and answers of one ``experiments`` cell run."""
 
-    def __init__(self, cfg: Dict[str, Any], mix: Dict[str, Any], seed: int):
+    def __init__(self, cfg: Dict[str, Any], mix: Dict[str, Any], seed: int,
+                 devices):
         import jax
 
-        from repro.core import run_fused
-
-        if mix["driver"] != "run_fused":
-            raise ValueError(f"unknown driver {mix['driver']!r}")
         self.cfg, self.mix, self.seed = cfg, mix, seed
         self.problem, self.consts = spec.problem(
-            cfg["problem"]["kind"]).build(cfg["problem"])
+            cfg["problem"]["kind"], BENCH_DIR).build(cfg["problem"])
         self.ea, self.mig = build_ea(cfg, mix["impl"])
         self.epochs = max_epochs(cfg)
         self.base = jax.random.key(seed)
-        self._drive = lambda key: run_fused(
-            self.problem, self.ea, self.mig, n_islands=cfg["islands"],
-            max_epochs=self.epochs, rng=key)
+        self._drive = spec.driver(mix["driver"], BENCH_DIR).make(
+            self.problem, self.ea, self.mig, cfg, self.epochs, devices)
         self.records: List[Dict[str, Any]] = []
         self._kept: Dict[int, Any] = {}
         self._checked = 0

@@ -10,8 +10,11 @@ the trace's host plane beside the harness's own spans, on the clock of the
 device events. JAX marks each eager program it launches with a
 ``PjitFunction(<name>)`` host event.
 
-This module reads both from the ``.xplane.pb`` that :mod:`.trace` reads;
-:mod:`.trace` and its ``Trace`` are left as they are.
+:func:`.trace.load` keeps both in its one parse of the ``.xplane.pb``:
+``Trace.program`` (names starting with ``trace.PREFIXES``) and
+``Trace.dispatches`` (the outermost ``PjitFunction`` events).
+:func:`from_trace` hands them to the split here; the ``driver_*`` metric
+readers take their part of it through :func:`reading`.
 
     cd bench && python3 -m harness.program <trace dir or .xplane.pb[.gz]>
 
@@ -21,19 +24,13 @@ prints the split of one trace as JSON (``bench/run.py --trace 1
 from __future__ import annotations
 
 import dataclasses
-import gzip
 import json
 import sys
-from pathlib import Path
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Set, Tuple
 
 from . import trace as trace_lib
 
 Event = trace_lib.Event
-# the program's span names start with one of these (repro.obs.trace)
-PREFIXES = ("driver.", "bridge.", "checkpoint.", "pool.", "server.")
-# JAX's host event for each program it launches
-DISPATCH = "PjitFunction("
 UNATTRIBUTED = "unattributed"
 
 
@@ -43,49 +40,14 @@ class Program:
     dispatches: List[Event]   # outermost PjitFunction events, by start
 
 
-def _outermost(events: List[Event]) -> List[Event]:
-    """Events of one thread not nested in an earlier one (JAX writes each
-    launch as a PjitFunction event inside another of the same name)."""
-    out: List[Event] = []
-    for e in sorted(events, key=lambda e: e.start):
-        if not out or e.start >= out[-1].end:
-            out.append(e)
-    return out
+def from_trace(tr: trace_lib.Trace) -> Program:
+    return Program(spans=tr.program, dispatches=tr.dispatches)
 
 
 def load(path) -> Program:
     """The program's spans and JAX's launches from one ``.xplane.pb``,
     gzipped or not, or the profiler's log directory."""
-    from jax.profiler import ProfileData
-
-    path = Path(path)
-    if path.is_dir():
-        path = trace_lib.find_xplane(path)
-    if path.suffix == ".gz":
-        data = ProfileData.from_serialized_xspace(
-            gzip.decompress(path.read_bytes()))
-    else:
-        data = ProfileData.from_file(str(path))
-    spans: List[Event] = []
-    dispatches: List[Event] = []
-    for plane in data.planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            launched = []
-            for e in line.events:
-                name = e.name
-                if name.startswith(PREFIXES):
-                    spans.append(Event(name, e.start_ns * 1e-9,
-                                       e.duration_ns * 1e-9,
-                                       trace_lib._stats(e)))
-                elif name.startswith(DISPATCH):
-                    launched.append(Event(name, e.start_ns * 1e-9,
-                                          e.duration_ns * 1e-9))
-            dispatches += _outermost(launched)
-    spans.sort(key=lambda e: e.start)
-    dispatches.sort(key=lambda e: e.start)
-    return Program(spans=spans, dispatches=dispatches)
+    return from_trace(trace_lib.load(path))
 
 
 def _innermost(spans: List[Event], t: float, default: str) -> str:
@@ -102,12 +64,12 @@ def idle_by_span(tr: trace_lib.Trace, prog: Program) -> Dict[str, float]:
     exps = tr.spans("experiment")
     if not exps or not tr.device:
         return {}
-    chip = min(tr.device)
     out: Dict[str, float] = {}
-    for x in exps:
+    for x, idle in zip(exps, trace_lib.gaps_within(tr, min(tr.device),
+                                                   exps)):
         inside = [s for s in prog.spans
                   if s.start < x.end and s.end > x.start]
-        for a, b in trace_lib.gaps(tr, chip, x.start, x.end):
+        for a, b in idle:
             cuts = sorted({a, b} | {t for s in inside
                                     for t in (s.start, s.end) if a < t < b})
             for p, q in zip(cuts, cuts[1:]):
@@ -147,9 +109,40 @@ def idle_gaps(tr: trace_lib.Trace, prog: Program,
     return named[:top]
 
 
+def in_experiments(tr: trace_lib.Trace) -> Set[str]:
+    """Names of the program's spans that overlap an ``experiment`` span."""
+    exps = tr.spans("experiment")
+    return {s.name for s in tr.program
+            if any(s.start < x.end and s.end > x.start for x in exps)}
+
+
+def reading(ctx, split, name: str) -> Optional[float]:
+    """Part ``name`` (a span, or ``unattributed``) of ``split``
+    (:func:`idle_by_span` or :func:`dispatches_by_span`) of the trace in a
+    metric reader's ``ctx``, as the reader reports it. None when there is
+    nothing to read: no experiment, no device, no program span inside an
+    experiment, or none named ``name`` (so a span renamed or not recorded
+    leaves its metric out rather than reading 0). A span that is there
+    with no idle time or launch under it reads 0. Each split, and the
+    names of the spans inside experiments, are worked out once per
+    ``ctx`` and kept there for the other parts."""
+    tr = ctx["trace"]
+    if not tr.spans("experiment") or not tr.device:
+        return None
+    if "program.names" not in ctx:
+        ctx["program.names"] = in_experiments(tr)
+    names = ctx["program.names"]
+    if not names or (name != UNATTRIBUTED and name not in names):
+        return None
+    key = "program." + split.__name__
+    if key not in ctx:
+        ctx[key] = split(tr, from_trace(tr))
+    return ctx[key].get(name, 0.0)
+
+
 def summary(path) -> Dict[str, object]:
     tr = trace_lib.load(path)
-    prog = load(path)
+    prog = from_trace(tr)
     return {"experiments": len(tr.spans("experiment")),
             "idle_ms_per_experiment": idle_by_span(tr, prog),
             "dispatches_per_experiment": dispatches_by_span(tr, prog),

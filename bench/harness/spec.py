@@ -1,18 +1,39 @@
 """Find what BENCHMARK.json names: cells, configurations, traffic mixes,
-problems, metric readers and kernel work counts, each by its name in a
-file of its own.
+the entries and drivers the mixes name, problems, metric readers and
+kernel work counts, each by its name in a file of its own.
 
-Everything that belongs to one configuration, traffic mix, per-layer
-metric or kernel sits in its own file, so a later change adds a cell or a
-metric by adding files and entries, never by editing one that is there:
+Everything that belongs to one configuration, traffic mix, entry, driver,
+per-layer metric or kernel sits in its own file, so a later change adds a
+cell or a metric by adding files and entries, never by editing one that is
+there:
 
 * ``configs/<config>.json``   the deployment as it is run
-* ``traffic/<mix>.json``      the mix: which entry it drives, with what
+* ``traffic/<mix>.json``      the mix: the ``entry`` it drives (and, for
+                              the ``experiments`` entry, the ``driver``),
+                              with what
+* ``harness/<entry>.py``      ``make(cfg, mix, seed, devices)``: one run's
+                              set-up, window and answers on the chips the
+                              cell asks for; the object has ``warm_up()``,
+                              ``window(seconds, annotate, traced)`` (the
+                              window's seconds), ``metrics(window_s)``,
+                              ``counts()`` (``attempted``, ``failed``),
+                              ``verify(control)`` (the numbers compared),
+                              ``answers()`` and ``numbers(answers,
+                              control)`` (the same in two steps, for
+                              ``control.py``), ``shape()`` (what the work
+                              counts need), ``notes()`` and ``close()``
+* ``drivers/<driver>.py``     ``make(problem, ea, mig, cfg, epochs,
+                              devices)``: a function from an experiment's
+                              key to its ``(islands, pool, epochs)``
 * ``problems/<kind>.py``      a configuration's problem: ``build(p)`` (the
                               program's problem and the reference's
                               constants), ``reference``, ``reference_bf16``
                               (the control) and ``random_population``
-* ``metrics/<metric>.py``     ``read(ctx) -> float | None``
+* ``metrics/<metric>.py``     ``read(ctx) -> float | None``; ``ctx`` holds
+                              the ``trace`` (:mod:`.trace`: device ops,
+                              harness and program spans, launches), the
+                              entry's ``shape``, the chip's ``peaks`` and
+                              the ``entry``
 * ``work/<kernel>.py``        ``MATCH`` (its events in the device trace)
                               and ``work(shape) -> {"ops", "bytes"}``
 """
@@ -73,6 +94,32 @@ def _module(path: Path, name: str) -> ModuleType:
     return mod
 
 
+def _maker(path: Path, modname: str, what: str, name: str) -> ModuleType:
+    """The module at ``path``, which has to define ``make``."""
+    if not path.is_file():
+        raise SpecError(f"no {what} {name!r} ({path})")
+    mod = _module(path, modname)
+    if not callable(getattr(mod, "make", None)):
+        raise SpecError(f"{what} {name!r} ({path}) defines no make()")
+    return mod
+
+
+def entry(name: str, bench_dir: Path = BENCH_DIR) -> ModuleType:
+    """The traffic entry ``harness/<name>.py``: ``make(cfg, mix, seed,
+    devices)`` gives one run's entry object. Loaded as a module of the
+    harness package, so that it imports its siblings relatively."""
+    return _maker(Path(bench_dir) / "harness" / f"{name}.py",
+                  f"harness.{name}", "traffic entry", name)
+
+
+def driver(name: str, bench_dir: Path = BENCH_DIR) -> ModuleType:
+    """The experiment driver ``drivers/<name>.py``: ``make(problem, ea,
+    mig, cfg, epochs, devices)`` gives a function from a key to
+    ``(islands, pool, epochs)``."""
+    return _maker(Path(bench_dir) / "drivers" / f"{name}.py",
+                  "bench_driver_" + name.replace(".", "_"), "driver", name)
+
+
 def problem(kind: str, bench_dir: Path = BENCH_DIR) -> ModuleType:
     path = Path(bench_dir) / "problems" / f"{kind}.py"
     if not path.is_file():
@@ -112,7 +159,10 @@ def check_consistent(bench: Dict[str, Any], root: Path = ROOT,
     for w in bench["workloads"]:
         try:
             cfg = config(bench, w["config"], root)
-            traffic(w["traffic"], bench_dir)
+            mix = traffic(w["traffic"], bench_dir)
+            entry(mix["entry"], bench_dir)
+            if "driver" in mix:
+                driver(mix["driver"], bench_dir)
             problem(cfg["problem"]["kind"], bench_dir)
         except SpecError as e:
             errs.append(f"{w['name']}: {e}")

@@ -1,16 +1,21 @@
 """Reduce a JAX profiler trace (``.xplane.pb``) to what the metric readers
-need: device operations per chip, the harness's own host spans, busy and
-idle time, kernel and collective time, and the breakdown that goes into
-the result line.
+need: device operations per chip, the harness's own host spans, the
+program's spans and JAX's launches, busy and idle time, kernel and
+collective time, and the breakdown that goes into the result line.
 
-The file is read with ``jax.profiler.ProfileData`` alone. Device planes
-are named ``/device:TPU:<n>``; their ``XLA Ops`` line holds one event per
-executed HLO operation, a Pallas kernel among them. Host spans are the
-``jax.profiler.TraceAnnotation`` events the harness writes on the same
-clock, so a device gap can be laid against what the host was doing.
+The file is read once, with ``jax.profiler.ProfileData`` alone. Device
+planes are named ``/device:TPU:<n>``; their ``XLA Ops`` line holds one
+event per executed HLO operation, a Pallas kernel among them. On the host
+planes, on the same clock, sit the ``jax.profiler.TraceAnnotation``
+events the harness writes (``HOST_SPANS``), the program's own dotted
+``component.verb`` spans (``PREFIXES``, ``repro.obs.trace``) and the
+``PjitFunction(<name>)`` event JAX writes for each program it launches,
+so a device gap can be laid against what the host was doing.
+:mod:`.program` splits idle time by the program's spans.
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import re
 from pathlib import Path
@@ -20,6 +25,10 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 # the harness's own spans: names it writes with TraceAnnotation
 HOST_SPANS = ("window", "experiment")
+# the program's span names start with one of these (repro.obs.trace)
+PREFIXES = ("driver.", "bridge.", "checkpoint.", "pool.", "server.")
+# JAX's host event for each program it launches
+DISPATCH = "PjitFunction("
 COLLECTIVE = re.compile(
     r"all-gather|all-reduce|collective-permute|all-to-all|reduce-scatter"
     r"|allgather|allreduce|collective", re.I)
@@ -45,6 +54,10 @@ class Event:
 class Trace:
     device: Dict[int, List[Event]]   # chip -> ops sorted by start
     host: List[Event]                # harness spans sorted by start
+    # the program's spans, sorted by start
+    program: List[Event] = dataclasses.field(default_factory=list)
+    # outermost PjitFunction events of each host thread, sorted by start
+    dispatches: List[Event] = dataclasses.field(default_factory=list)
 
     def spans(self, name: str) -> List[Event]:
         return [e for e in self.host if e.name == name]
@@ -61,6 +74,20 @@ def _stats(ev) -> Tuple[Tuple[str, str], ...]:
         return tuple((str(k), str(v)) for k, v in ev.stats)
     except (TypeError, ValueError):
         return ()
+
+
+def _event(e) -> Event:
+    return Event(e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9, _stats(e))
+
+
+def outermost(events: List[Event]) -> List[Event]:
+    """Events of one thread not nested in an earlier one (JAX writes each
+    launch as a PjitFunction event inside another of the same name)."""
+    out: List[Event] = []
+    for e in sorted(events, key=lambda e: e.start):
+        if not out or e.start >= out[-1].end:
+            out.append(e)
+    return out
 
 
 def find_xplane(logdir: Path) -> Path:
@@ -87,25 +114,31 @@ def load(path: Path) -> Trace:
         data = ProfileData.from_file(str(path))
     device: Dict[int, List[Event]] = {}
     host: List[Event] = []
+    program: List[Event] = []
+    dispatches: List[Event] = []
     for plane in data.planes:
         m = DEVICE_PLANE.match(plane.name)
         for line in plane.lines:
             if m is not None:
                 if line.name != OPS_LINE:
                     continue
-                evs = device.setdefault(int(m.group(1)), [])
-                for e in line.events:
-                    evs.append(Event(e.name, e.start_ns * 1e-9,
-                                     e.duration_ns * 1e-9, _stats(e)))
+                device.setdefault(int(m.group(1)), []).extend(
+                    map(_event, line.events))
             elif plane.name.startswith("/host:"):
+                launched = []
                 for e in line.events:
-                    if e.name in HOST_SPANS:
-                        host.append(Event(e.name, e.start_ns * 1e-9,
-                                          e.duration_ns * 1e-9, _stats(e)))
-    for evs in device.values():
+                    name = e.name
+                    if name in HOST_SPANS:
+                        host.append(_event(e))
+                    elif name.startswith(PREFIXES):
+                        program.append(_event(e))
+                    elif name.startswith(DISPATCH):
+                        launched.append(_event(e))
+                dispatches += outermost(launched)
+    for evs in (*device.values(), host, program, dispatches):
         evs.sort(key=lambda e: e.start)
-    host.sort(key=lambda e: e.start)
-    return Trace(device=device, host=host)
+    return Trace(device=device, host=host, program=program,
+                 dispatches=dispatches)
 
 
 # ---------------------------------------------------------------------------
@@ -155,6 +188,29 @@ def gaps(trace: Trace, chip: int, lo: float,
         at = max(at, e)
     if hi > at:
         out.append((at, hi))
+    return out
+
+
+def gaps_within(trace: Trace, chip: int,
+                spans: Sequence[Event]) -> List[List[Tuple[float, float]]]:
+    """Idle intervals of ``chip`` inside each of ``spans`` (sorted and
+    disjoint, as the harness's experiments are): what :func:`gaps` gives
+    for each span alone, with the device's events merged once for all."""
+    if not spans:
+        return []
+    every = gaps(trace, chip, spans[0].start, max(x.end for x in spans))
+    ends = [b for _, b in every]
+    out = []
+    for x in spans:
+        inside = []
+        for i in range(bisect.bisect_right(ends, x.start), len(every)):
+            a, b = every[i]
+            if a >= x.end:
+                break
+            a, b = max(a, x.start), min(b, x.end)
+            if b > a:
+                inside.append((a, b))
+        out.append(inside)
     return out
 
 

@@ -10,9 +10,7 @@ def read(ctx):
     spans = tr.spans("experiment")
     if not spans or not tr.device:
         return None
-    chip = min(tr.device)
     idle = 0.0
-    for s in spans:
-        idle += sum(e - b for b, e in trace_lib.gaps(tr, chip, s.start,
-                                                       s.end))
+    for inside in trace_lib.gaps_within(tr, min(tr.device), spans):
+        idle += sum(e - b for b, e in inside)
     return 1e3 * idle / len(spans)

@@ -7,11 +7,14 @@
 The cell (``BENCHMARK.json``'s ``workloads``) names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<mix>.json``); the mix names the entry it drives
-(``bench/harness/<entry>.py``), the configuration its problem
-(``bench/problems/<kind>.py``). A run sets up (compile cache, the pinned
-tile, problem data, one warm-up of every shape), measures for
-``--seconds``, checks what the timed path produced against the plain
-reference, and prints one JSON line last on stdout:
+(``bench/harness/<entry>.py``, whose ``make(cfg, mix, seed, devices)`` is
+handed the chips the cell asks for) and what the entry reads, such as a
+driver (``bench/drivers/<driver>.py``); the configuration names its
+problem (``bench/problems/<kind>.py``). ``harness/spec.py`` lists the
+interface of each. A run sets up (compile cache, the pinned tile, problem
+data, one warm-up of every shape), measures for ``--seconds``, checks
+what the timed path produced against the plain reference, and prints one
+JSON line last on stdout:
 
 * ``--trace 0``: the cell's end-to-end metrics, taken on the host clock;
 * ``--trace 1``: its per-layer metrics, read from a profiler trace of the
@@ -73,11 +76,9 @@ def device_info(devs_used):
             "count": len(devs), "memory_peak_bytes": peak}
 
 
-def make_entry(cfg, mix, seed):
-    if mix["entry"] == "experiments":
-        from harness.experiments import Experiments
-        return Experiments(cfg, mix, seed)
-    raise spec.SpecError(f"unknown traffic entry {mix['entry']!r}")
+def make_entry(cfg, mix, seed, devices, bench_dir=BENCH):
+    """The entry the mix names (``harness/<entry>.py``), on ``devices``."""
+    return spec.entry(mix["entry"], bench_dir).make(cfg, mix, seed, devices)
 
 
 def _annotate(on: bool):
@@ -90,24 +91,25 @@ def _annotate(on: bool):
 
 def run_cell(bench, cell, cfg, mix, *, seed: int, seconds: float,
              trace: bool, t_start: float, devs_used, trace_dir=None,
-             control: bool = False, pin_kind=None):
+             control: bool = False, pin_kind=None, bench_dir=BENCH):
     """Set up, measure and check one run; returns the result dict. With
     ``pin_kind`` (the chip's device kind) the configuration's tile is
-    pinned first (:mod:`harness.tiles`)."""
+    pinned first (:mod:`harness.tiles`). Entries and metric readers are
+    found under ``bench_dir``."""
     if pin_kind is not None:
         tiles.pin(cfg, pin_kind)
-    entry = make_entry(cfg, mix, seed)
+    entry = make_entry(cfg, mix, seed, devs_used, bench_dir)
     try:
         return _measure(bench, cell, cfg, entry, seconds=seconds,
                         trace=trace, t_start=t_start, devs_used=devs_used,
                         trace_dir=trace_dir, control=control,
-                        pin_kind=pin_kind)
+                        pin_kind=pin_kind, bench_dir=bench_dir)
     finally:
         entry.close()
 
 
 def _measure(bench, cell, cfg, entry, *, seconds, trace, t_start, devs_used,
-             trace_dir, control, pin_kind):
+             trace_dir, control, pin_kind, bench_dir):
     import jax
 
     from harness import check, peaks as peaks_lib
@@ -146,7 +148,7 @@ def _measure(bench, cell, cfg, entry, *, seconds, trace, t_start, devs_used,
                "entry": entry}
         metrics = {}
         for m in spec.cell_metrics(bench, cell["name"], "per_layer"):
-            v = spec.metric_reader(m["name"]).read(ctx)
+            v = spec.metric_reader(m["name"], bench_dir).read(ctx)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         lo, hi = tr.window()

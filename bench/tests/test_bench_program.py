@@ -51,7 +51,7 @@ def test_outermost_drops_nested_launches():
            _ev("PjitFunction(add)", 1.1, 0.3),
            _ev("PjitFunction(iota)", 2.0, 0.1),
            _ev("PjitFunction(iota)", 2.0, 0.1)]
-    assert [(e.name, e.start) for e in P._outermost(evs)] == [
+    assert [(e.name, e.start) for e in T.outermost(evs)] == [
         ("PjitFunction(add)", 1.0), ("PjitFunction(iota)", 2.0)]
 
 

@@ -55,8 +55,12 @@ def test_every_cell_reports_enough(bench):
 
 def test_metrics_of_one_layer_agree_on_its_name(bench):
     for m in bench["per_layer"]:
-        assert "workloads" in m, m["name"]
+        # without a list a metric is read in every cell, later ones too
+        assert m.get("workloads", ["every cell"]), m["name"]
         assert m["layer"] and "\n" not in m["layer"]
+    # no two spellings of one layer
+    layers = {m["layer"] for m in bench["per_layer"]}
+    assert len({" ".join(x.lower().split()) for x in layers}) == len(layers)
 
 
 def test_new_files_are_found(tmp_path, bench):
